@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from repro import index as ix
 from repro.core.cdf import true_ranks
 from repro.dist.sharded_index import ShardedIndex, sharded_lookup
-from repro.dist.sharding import ShardingCtx
+from repro.dist.sharding import ShardingCtx, make_mesh
 
 from .common import time_fn
 
@@ -52,7 +52,7 @@ PARAMS = {
 
 def _mesh_ctx(n_shards: int):
     if n_shards > 1 and len(jax.devices()) >= n_shards:
-        mesh = jax.make_mesh((1, n_shards), ("data", "model"))
+        mesh = make_mesh((1, n_shards), ("data", "model"))
         return ShardingCtx(mesh=mesh)
     return None
 

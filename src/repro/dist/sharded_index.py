@@ -456,6 +456,29 @@ class ShardedIndex:
             info={"shard": s, **self.info},
         )
 
+    def place(self, ctx) -> "ShardedIndex":
+        """The tier laid out over ``ctx``'s ``tp`` axis: shard ``s``'s
+        leaves, table row, count and offset on the devices of ``tp``
+        index ``s``, the fences replicated — the layout the ``a2a`` and
+        ``allgather`` modes read, so no call moves the tier."""
+        from jax.sharding import NamedSharding
+
+        axes = ctx.mesh_axes("tp")
+        if ctx.n("tp") != self.n_shards:
+            raise ValueError(
+                f"mesh tp extent {ctx.n('tp')} does not match n_shards={self.n_shards}"
+            )
+        shard = NamedSharding(ctx.mesh, P(axes if len(axes) > 1 else axes[0]))
+        put = partial(jax.device_put, device=shard)
+        return ShardedIndex(
+            index=jax.tree_util.tree_map(put, self.index),
+            tables=put(self.tables),
+            fences=jax.device_put(self.fences, NamedSharding(ctx.mesh, P())),
+            counts=put(self.counts),
+            offsets=put(self.offsets),
+            info=self.info,
+        )
+
     def space_bytes(self) -> int:
         """Model bytes across the tier + the router's fence/offset arrays."""
         per_shard = self.shard(0).space_bytes()
@@ -641,8 +664,6 @@ def _lookup_vmapped(sidx: ShardedIndex, queries, backend: str):
 
 @partial(jax.jit, static_argnames=("mesh", "axes", "backend", "cap"))
 def _lookup_a2a(sidx: ShardedIndex, queries, mesh, axes, backend: str, cap: int):
-    from jax.experimental.shard_map import shard_map
-
     count_trace(f"sharded:{sidx.kind}", f"a2a:{backend}")
     n_shards = sidx.n_shards
     ax = axes if len(axes) > 1 else axes[0]
@@ -663,19 +684,17 @@ def _lookup_a2a(sidx: ShardedIndex, queries, mesh, axes, backend: str, cap: int)
         # unsort; entries that never fit a slot keep the DROPPED sentinel
         return collectives.unbucket_inverse(back, slots, valid, order, b_loc, DROPPED)
 
-    return shard_map(
+    return jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(P(ax), P(ax), P(ax), P(ax), P(None), P(ax)),
         out_specs=P(ax),
-        check_rep=False,
+        check_vma=False,
     )(sidx.index, sidx.tables, sidx.counts, sidx.offsets, sidx.fences, queries)
 
 
 @partial(jax.jit, static_argnames=("mesh", "axes", "backend"))
 def _lookup_allgather(sidx: ShardedIndex, queries, mesh, axes, backend: str):
-    from jax.experimental.shard_map import shard_map
-
     count_trace(f"sharded:{sidx.kind}", f"allgather:{backend}")
     ax = axes if len(axes) > 1 else axes[0]
 
@@ -687,12 +706,12 @@ def _lookup_allgather(sidx: ShardedIndex, queries, mesh, axes, backend: str):
         mine = owner.astype(jnp.int64) == me.astype(jnp.int64)
         return lax.psum(jnp.where(mine, g, jnp.zeros_like(g)), axes)
 
-    return shard_map(
+    return jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(P(ax), P(ax), P(ax), P(ax), P(None), P(None)),
         out_specs=P(None),
-        check_rep=False,
+        check_vma=False,
     )(sidx.index, sidx.tables, sidx.counts, sidx.offsets, sidx.fences, queries)
 
 
@@ -749,7 +768,7 @@ def sharded_lookup(
     Example — a 4-shard PGM tier on a ``tp=4`` mesh::
 
         sidx = ShardedIndex.build("PGM", table, n_shards=4, eps=64)
-        ctx = ShardingCtx(mesh=jax.make_mesh((1, 4), ("data", "model")))
+        ctx = ShardingCtx(mesh=make_mesh((1, 4), ("data", "model")))
         ranks = sharded_lookup(sidx, queries, ctx, backend="pallas")
         # single-device fallback, still exact, no collectives:
         ranks = sharded_lookup(sidx, queries, mode="ref")

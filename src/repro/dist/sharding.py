@@ -128,7 +128,17 @@ class ShardingCtx:
         return out
 
 
+def make_mesh(shape: tuple, axes: tuple, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    Since JAX 0.8 ``jax.make_mesh`` defaults to ``Explicit`` axes, under
+    which ``with_sharding_constraint`` and the sharded contractions of
+    the model code are refused.  Every mesh of this repo is built here.
+    """
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=axis_types, devices=devices)
+
+
 def single_device_ctx(profile: str = "tp_fsdp") -> ShardingCtx:
     """A (1, 1) ``('data', 'model')`` mesh on the first local device."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    return ShardingCtx(mesh=mesh, profile=profile)
+    return ShardingCtx(mesh=make_mesh((1, 1), ("data", "model")), profile=profile)

@@ -32,7 +32,6 @@ from dataclasses import dataclass
 from typing import Callable
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from repro.core import search
@@ -44,6 +43,7 @@ from repro.core.pgm import build_pgm, build_pgm_bicriteria
 from repro.core.radix_spline import build_rs
 from repro.core.rmi import build_rmi
 from repro.core.sy_rmi import build_sy_rmi
+from repro.kernels import ops as kernel_ops
 
 from .index import Index
 from .registry import register
@@ -138,7 +138,7 @@ def _kary_pallas_fallback(index: Index, table, q):
     nq = q.shape[0]
     tile = min(512, _pow2ceil(nq))
     qhi, qlo = _pad_queries([qhi, qlo], tile)
-    interpret = jax.default_backend() != "tpu"
+    interpret = kernel_ops._interpret()
     out = kary_search_pallas(qhi, qlo, thi, tlo, k=LANES, tile_q=tile, interpret=interpret)
     return out[:nq].astype(POS_DTYPE)
 
@@ -154,7 +154,7 @@ def _kary_pallas_batched(index: Index, tables, queries):
     nq = queries.shape[1]
     tile = min(512, _pow2ceil(nq))
     qhi, qlo = _pad_queries([qhi, qlo], tile, axis=1)
-    interpret = jax.default_backend() != "tpu"
+    interpret = kernel_ops._interpret()
     out = batched_kary_search_pallas(qhi, qlo, thi, tlo, k=LANES, tile_q=tile, interpret=interpret)
     return out[:, :nq].astype(POS_DTYPE)
 
@@ -312,7 +312,7 @@ def _rmi_pallas(idx: Index, table, q):
         a["k_rhi"],
         steps=idx.s("ksteps"),
         tile_q=tile,
-        interpret=jax.default_backend() != "tpu",
+        interpret=kernel_ops._interpret(),
     )
     return out[:nq].astype(POS_DTYPE)
 
@@ -350,7 +350,7 @@ def _rmi_pallas_batched(idx: Index, tables, queries):
         a["k_rhi"],
         steps=idx.s("ksteps"),
         tile_q=tile,
-        interpret=jax.default_backend() != "tpu",
+        interpret=kernel_ops._interpret(),
     )
     return out[:, :nq].astype(POS_DTYPE)
 
@@ -494,7 +494,7 @@ def _pgm_pallas(idx: Index, table, q):
         levels=idx.s("levels"),
         steps=idx.s("pksteps"),
         tile_q=tile,
-        interpret=jax.default_backend() != "tpu",
+        interpret=kernel_ops._interpret(),
     )
     return out[:nq].astype(POS_DTYPE)
 
@@ -538,7 +538,7 @@ def _pgm_pallas_batched(idx: Index, tables, queries):
         levels=idx.s("levels"),
         steps=idx.s("pksteps"),
         tile_q=tile,
-        interpret=jax.default_backend() != "tpu",
+        interpret=kernel_ops._interpret(),
     )
     return out[:, :nq].astype(POS_DTYPE)
 
@@ -691,7 +691,7 @@ def _rs_pallas(idx: Index, table, q):
         ksteps=idx.s("ksteps"),
         steps=idx.s("rk_epi"),
         tile_q=tile,
-        interpret=jax.default_backend() != "tpu",
+        interpret=kernel_ops._interpret(),
     )
     return out[:nq].astype(POS_DTYPE)
 
@@ -741,7 +741,7 @@ def _rs_pallas_batched(idx: Index, tables, queries):
         ksteps=idx.s("ksteps"),
         steps=idx.s("rk_epi"),
         tile_q=tile,
-        interpret=jax.default_backend() != "tpu",
+        interpret=kernel_ops._interpret(),
     )
     return out[:, :nq].astype(POS_DTYPE)
 

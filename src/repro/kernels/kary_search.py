@@ -15,12 +15,13 @@ from __future__ import annotations
 import functools
 import math
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .rmi_search import _le_u64, DEFAULT_TILE_Q
+from .rmi_search import _le_u64, _ONE, DEFAULT_TILE_Q
 
 LANES = 128
 
@@ -54,21 +55,22 @@ def _kary_body(qhi, qlo, thi, tlo, *, n: int, k: int, steps: int):
     """The lane-wide k-ary search on plain arrays (shared by the
     single-table and batched kernels)."""
     tq = qhi.shape[0]
+    k32 = np.int32(k)  # 32-bit constants: see rmi_search._ZERO
 
     base = jnp.zeros((tq,), jnp.int32)
     length = jnp.full((tq,), n, jnp.int32)
-    frac = lax.broadcasted_iota(jnp.int32, (tq, k - 1), 1) + 1  # 1..k-1
+    frac = lax.broadcasted_iota(jnp.int32, (tq, k - 1), 1) + _ONE  # 1..k-1
 
     def body(_, carry):
         base, length = carry
-        fence = base[:, None] + (frac * length[:, None]) // k  # (TQ, K-1)
+        fence = base[:, None] + (frac * length[:, None]) // k32  # (TQ, K-1)
         fhi = jnp.take(thi, fence)
         flo = jnp.take(tlo, fence)
         le = _le_u64(fhi, flo, qhi[:, None], qlo[:, None])
         seg = jnp.sum(le, axis=1, dtype=jnp.int32)  # segment index
-        new_base = base + (seg * length) // k
-        new_len = (jnp.minimum(seg + 1, k) * length) // k - (seg * length) // k
-        keep = length > k
+        new_base = base + (seg * length) // k32
+        new_len = (jnp.minimum(seg + _ONE, k32) * length) // k32 - (seg * length) // k32
+        keep = length > k32
         base = jnp.where(keep, new_base, base)
         length = jnp.where(keep, new_len, length)
         return base, length
@@ -77,12 +79,12 @@ def _kary_body(qhi, qlo, thi, tlo, *, n: int, k: int, steps: int):
 
     # final lane sweep: window now <= k wide; one (TQ, K) gather + count
     offs = lax.broadcasted_iota(jnp.int32, (tq, k), 1)
-    idx = jnp.minimum(base[:, None] + offs, n - 1)
+    idx = jnp.minimum(base[:, None] + offs, np.int32(n - 1))
     vhi = jnp.take(thi, idx)
     vlo = jnp.take(tlo, idx)
     le = _le_u64(vhi, vlo, qhi[:, None], qlo[:, None]) & (offs < length[:, None])
     cnt = jnp.sum(le, axis=1, dtype=jnp.int32)
-    return base + cnt - 1
+    return base + cnt - _ONE
 
 
 def _kary_kernel(qhi_ref, qlo_ref, thi_ref, tlo_ref, out_ref, *, n: int, k: int, steps: int):

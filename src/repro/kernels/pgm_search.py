@@ -47,12 +47,13 @@ from __future__ import annotations
 
 import functools
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .rmi_search import _le_u64, DEFAULT_TILE_Q
+from .rmi_search import _F32_HI, _F32_LO, _le_u64, _ONE, _ZERO, DEFAULT_TILE_Q
 
 
 def _bounded_ub_limbs(khi, klo, qhi, qlo, base, length, *, steps: int):
@@ -61,11 +62,11 @@ def _bounded_ub_limbs(khi, klo, qhi, qlo, base, length, *, steps: int):
 
     def body(_, carry):
         b, n = carry
-        half = n >> 1
+        half = n >> _ONE
         mid = b + half
-        go_right = _le_u64(jnp.take(khi, mid), jnp.take(klo, mid), qhi, qlo) & (n > 1)
+        go_right = _le_u64(jnp.take(khi, mid), jnp.take(klo, mid), qhi, qlo) & (n > _ONE)
         b = jnp.where(go_right, mid, b)
-        n = n - jnp.where(n > 1, half, 0)
+        n = n - jnp.where(n > _ONE, half, _ZERO)
         return b, n
 
     b, _ = lax.fori_loop(0, steps, body, (base, length))
@@ -101,11 +102,11 @@ def _pgm_body(
         u0 = jnp.take(u0_a, base_k + seg)
         slope = jnp.take(slope_a, base_k + seg)
         r0 = jnp.take(r0_a, base_r + seg)
-        r1 = jnp.take(r0_a, base_r + seg + 1)
-        pred = r0.astype(jnp.float32) + slope * jnp.maximum(u - u0, 0.0)
-        pred = jnp.clip(pred, -1.0e9, 1.0e9)  # gap blow-ups: clamp pre-cast
-        b_lo = jnp.maximum(r0 - 1, 0)
-        b_hi = r1 - 1
+        r1 = jnp.take(r0_a, base_r + seg + _ONE)
+        pred = r0.astype(jnp.float32) + slope * jnp.maximum(u - u0, np.float32(0.0))
+        pred = jnp.clip(pred, _F32_LO, _F32_HI)  # gap blow-ups: clamp pre-cast
+        b_lo = jnp.maximum(r0 - _ONE, _ZERO)
+        b_hi = r1 - _ONE
         # clamp the predicted CENTER into the fence range before widening:
         # an f32 u-resolution collapse (dense cluster inside a huge key
         # span) can push pred thousands of ranks past the segment, and
@@ -115,18 +116,19 @@ def _pgm_body(
         # |center - true| and the measured-ε guarantee survives.
         p_lo = jnp.clip(jnp.floor(pred).astype(jnp.int32), b_lo, b_hi)
         p_hi = jnp.clip(jnp.ceil(pred).astype(jnp.int32), b_lo, b_hi)
-        lo = jnp.clip(p_lo - (eps + 1), b_lo, b_hi)
-        hi = jnp.clip(p_hi + (eps + 1), b_lo, b_hi)
+        lo = jnp.clip(p_lo - (eps + _ONE), b_lo, b_hi)
+        hi = jnp.clip(p_hi + (eps + _ONE), b_lo, b_hi)
         if lvl + 1 < levels:
             base_n = off[lvl + 1]
-            ub = _bounded_ub_limbs(khi, klo, qhi, qlo, base_n + lo, hi - lo + 1, steps=steps)
-            seg = jnp.clip(ub - base_n - 1, 0, sizes[lvl + 1] - 1)
+            ub = _bounded_ub_limbs(khi, klo, qhi, qlo, base_n + lo, hi - lo + _ONE, steps=steps)
+            seg = jnp.clip(ub - base_n - _ONE, _ZERO, sizes[lvl + 1] - _ONE)
         else:
             # leaf level: r0 indexes the table — final ε-window search
-            lo = jnp.clip(lo, 0, n - 1)
-            hi = jnp.clip(hi, 0, n - 1)
-            ub = _bounded_ub_limbs(thi, tlo, qhi, qlo, lo, hi - lo + 1, steps=steps)
-            return ub - 1
+            last = np.int32(n - 1)
+            lo = jnp.clip(lo, _ZERO, last)
+            hi = jnp.clip(hi, _ZERO, last)
+            ub = _bounded_ub_limbs(thi, tlo, qhi, qlo, lo, hi - lo + _ONE, steps=steps)
+            return ub - _ONE
     raise AssertionError("unreachable")
 
 

@@ -26,12 +26,22 @@ from __future__ import annotations
 
 import functools
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
 DEFAULT_TILE_Q = 512
+
+# Kernel-body constants carry explicit 32-bit dtypes: under the global
+# x64 flag a bare Python scalar traces as a 64-bit constant plus a
+# convert, which Mosaic cannot lower.  NumPy scalars trace as typed
+# literals (a jnp scalar would be a captured constant, also refused).
+_ZERO = np.int32(0)
+_ONE = np.int32(1)
+_F32_LO = np.float32(-1.0e9)
+_F32_HI = np.float32(1.0e9)
 
 
 def _le_u64(khi, klo, qhi, qlo):
@@ -51,8 +61,10 @@ def _rmi_body(u, qhi, qlo, thi, tlo, c, slope_a, icept_a, eps_a, rlo_a, rhi_a, *
     # |p| ~ 1e15 in f32, and an out-of-range float->int32 cast is
     # implementation-defined garbage that survives the later clips.
     p_root = ((c[3] * u + c[2]) * u + c[1]) * u + c[0]
-    p_root = jnp.clip(p_root, -1.0e9, 1.0e9)  # b/n <= 1 keeps the product in i32
-    leaf = jnp.clip(jnp.floor(p_root * (b / n)).astype(jnp.int32), 0, b - 1)
+    p_root = jnp.clip(p_root, _F32_LO, _F32_HI)  # b/n <= 1 keeps the product in i32
+    scale = np.float32(b / n)
+    leaf = jnp.floor(p_root * scale).astype(jnp.int32)
+    leaf = jnp.clip(leaf, _ZERO, np.int32(b - 1))
 
     # --- stage 2: leaf linear predict + guaranteed window ---
     slope = jnp.take(slope_a, leaf)
@@ -60,7 +72,7 @@ def _rmi_body(u, qhi, qlo, thi, tlo, c, slope_a, icept_a, eps_a, rlo_a, rhi_a, *
     eps = jnp.take(eps_a, leaf)
     rlo = jnp.take(rlo_a, leaf)
     rhi = jnp.take(rhi_a, leaf)
-    p = jnp.clip(slope * u + icept, -1.0e9, 1.0e9)  # +/-eps stays inside i32
+    p = jnp.clip(slope * u + icept, _F32_LO, _F32_HI)  # +/-eps stays inside i32
     # clamp the predicted CENTER into the leaf fences before widening: a
     # prediction blown far past the leaf (f32 u collapse on dense
     # clusters) would otherwise collapse the ±ε window to one fence
@@ -77,18 +89,18 @@ def _rmi_body(u, qhi, qlo, thi, tlo, c, slope_a, icept_a, eps_a, rlo_a, rhi_a, *
 
     def body(_, carry):
         base, length = carry
-        half = length >> 1
+        half = length >> _ONE
         mid = base + half
         khi = jnp.take(thi, mid)
         klo = jnp.take(tlo, mid)
-        go_right = _le_u64(khi, klo, qhi, qlo) & (length > 1)
+        go_right = _le_u64(khi, klo, qhi, qlo) & (length > _ONE)
         base = jnp.where(go_right, mid, base)
-        length = length - jnp.where(length > 1, half, 0)
+        length = length - jnp.where(length > _ONE, half, _ZERO)
         return base, length
 
     base, _ = lax.fori_loop(0, steps, body, (base, length))
     le = _le_u64(jnp.take(thi, base), jnp.take(tlo, base), qhi, qlo)
-    return base + le.astype(jnp.int32) - 1
+    return base + le.astype(jnp.int32) - _ONE
 
 
 def _rmi_kernel(

@@ -36,12 +36,13 @@ from __future__ import annotations
 
 import functools
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .pgm_search import _bounded_ub_limbs
-from .rmi_search import DEFAULT_TILE_Q
+from .rmi_search import _F32_HI, _F32_LO, _ONE, _ZERO, DEFAULT_TILE_Q
 
 
 def _rs_body(
@@ -66,28 +67,29 @@ def _rs_body(
 ):
     """The fused three-stage lookup on plain arrays."""
     # --- stage 1: radix-table gather bounds the knot range ---
-    lo_k = jnp.maximum(jnp.take(radix, prefix) - 1, 0)
-    hi_k = jnp.take(radix, prefix + 1)
-    length = jnp.maximum(hi_k - lo_k, 1)
+    lo_k = jnp.maximum(jnp.take(radix, prefix) - _ONE, _ZERO)
+    hi_k = jnp.take(radix, prefix + _ONE)
+    length = jnp.maximum(hi_k - lo_k, _ONE)
 
     # --- stage 2: exact knot search (limb compare) + f32 interpolation ---
     ub = _bounded_ub_limbs(khi, klo, qhi, qlo, lo_k, length, steps=ksteps)
-    j = jnp.clip(ub - 1, 0, m_valid - 2)
+    j = jnp.clip(ub - _ONE, _ZERO, m_valid - np.int32(2))
     y1 = jnp.take(rank_a, j).astype(jnp.float32)
-    pred = y1 + jnp.take(slope_a, j) * jnp.maximum(u - jnp.take(u0_a, j), 0.0)
-    pred = jnp.clip(pred, -1.0e9, 1.0e9)
+    pred = y1 + jnp.take(slope_a, j) * jnp.maximum(u - jnp.take(u0_a, j), np.float32(0.0))
+    pred = jnp.clip(pred, _F32_LO, _F32_HI)
     # clamp the predicted CENTER into the table before widening (see
     # pgm_search: an f32 u-resolution collapse can push pred far past
     # the table and collapse the ±ε window to the last slot; the true
     # rank is always in [0, n-1], so clamping the center is sound).
-    p_lo = jnp.clip(jnp.floor(pred).astype(jnp.int32), 0, n - 1)
-    p_hi = jnp.clip(jnp.ceil(pred).astype(jnp.int32), 0, n - 1)
-    lo = jnp.clip(p_lo - eps, 0, n - 1)
-    hi = jnp.clip(p_hi + eps, 0, n - 1)
+    last = np.int32(n - 1)
+    p_lo = jnp.clip(jnp.floor(pred).astype(jnp.int32), _ZERO, last)
+    p_hi = jnp.clip(jnp.ceil(pred).astype(jnp.int32), _ZERO, last)
+    lo = jnp.clip(p_lo - eps, _ZERO, last)
+    hi = jnp.clip(p_hi + eps, _ZERO, last)
 
     # --- stage 3: ε-window probe over the table limbs ---
-    ub_t = _bounded_ub_limbs(thi, tlo, qhi, qlo, lo, hi - lo + 1, steps=steps)
-    return ub_t - 1
+    ub_t = _bounded_ub_limbs(thi, tlo, qhi, qlo, lo, hi - lo + _ONE, steps=steps)
+    return ub_t - _ONE
 
 
 def _rs_kernel(

@@ -9,15 +9,15 @@ over 'pod' cross the inter-pod links).
 
 from __future__ import annotations
 
-import jax
+from repro.dist.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for multi-device CPU tests (XLA_FLAGS device count)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

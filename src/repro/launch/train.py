@@ -35,7 +35,7 @@ def main():
     import jax
     import repro  # noqa: F401
     from repro import configs
-    from repro.dist.sharding import ShardingCtx, single_device_ctx
+    from repro.dist.sharding import ShardingCtx, make_mesh, single_device_ctx
     from repro.launch import steps
     from repro.train import TrainConfig, init_train_state, loop
 
@@ -51,7 +51,7 @@ def main():
         import math
 
         d = int(math.sqrt(n_dev))
-        mesh = jax.make_mesh((n_dev // d, d), ("data", "model"))
+        mesh = make_mesh((n_dev // d, d), ("data", "model"))
         ctx = ShardingCtx(mesh=mesh, profile=profile_for(spec))
 
     tcfg = TrainConfig(

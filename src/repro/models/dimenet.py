@@ -184,7 +184,6 @@ def _edge_axes(ctx):
 def _padded_geometry(vec, tri_kj, cfg: DimeNetConfig, ctx):
     """sbf (E_loc rows): one explicit bf16 all-gather of edge vectors,
     then fully local gathers/angles."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = _edge_axes(ctx)
@@ -206,19 +205,18 @@ def _padded_geometry(vec, tri_kj, cfg: DimeNetConfig, ctx):
 
     if not axes:
         return block(vec, tri_kj)
-    return shard_map(
+    return jax.shard_map(
         block,
         mesh=ctx.mesh,
         in_specs=(P(spec, None), P(spec, None)),
         out_specs=P(spec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(vec, tri_kj)
 
 
 def _padded_interaction(m, sbf, tri_kj, blk, cfg: DimeNetConfig, ctx):
     """Per-edge triplet aggregation: ONE bf16 all-gather of messages,
     local gathers, masked row-sum — no segment_sum, no psum."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dt = m.dtype
@@ -239,12 +237,12 @@ def _padded_interaction(m, sbf, tri_kj, blk, cfg: DimeNetConfig, ctx):
 
     if not axes:
         return block(m, sbf, tri_kj)
-    return shard_map(
+    return jax.shard_map(
         block,
         mesh=ctx.mesh,
         in_specs=(P(spec, None), P(spec, None, None), P(spec, None)),
         out_specs=P(spec, None),
-        check_rep=False,
+        check_vma=False,
     )(m, sbf, tri_kj)
 
 

@@ -72,7 +72,6 @@ def _a2a_lookup(table, ids, ctx, cap_factor: float = 2.0):
     vectors actually requested (vs the psum of full batch in allreduce
     mode).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = ctx.mesh
@@ -112,12 +111,12 @@ def _a2a_lookup(table, ids, ctx, cap_factor: float = 2.0):
         return out.reshape(local_ids.shape[0], f, d)
 
     dp_spec = dp_axes if len(dp_axes) > 1 else (dp_axes[0] if dp_axes else None)
-    return shard_map(
+    return jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(P(axes, None), P(dp_spec, None)),
         out_specs=P(dp_spec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(table, ids)
 
 

@@ -21,7 +21,6 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import search
@@ -126,7 +125,7 @@ def moe_ffn(x2d, moe_params, cfg, ctx, *, replicated_tokens: bool = False):
         return y
 
     fsdp_spec = fsdp_axes if len(fsdp_axes) > 1 else (fsdp_axes[0] if fsdp_axes else None)
-    return shard_map(
+    return jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(
@@ -137,5 +136,5 @@ def moe_ffn(x2d, moe_params, cfg, ctx, *, replicated_tokens: bool = False):
             P(ep_spec, None, fsdp_spec),  # wd (E, ffe, d)
         ),
         out_specs=P(dp_spec, None),
-        check_rep=False,
+        check_vma=False,
     )(x2d, moe_params["router"], moe_params["wg"], moe_params["wu"], moe_params["wd"])

@@ -20,16 +20,16 @@ import numpy as np
 import jax, jax.numpy as jnp
 import repro
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.dist.sharding import ShardingCtx
+from repro.dist.sharding import ShardingCtx, make_mesh
 from repro.configs import get as get_arch
 from repro.launch import steps
 from repro.models import transformer, recsys
 from repro.train import TrainConfig, init_train_state, make_train_step, checkpoint
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 ctx = ShardingCtx(mesh=mesh)
-mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+mesh1 = make_mesh((1, 1), ("data", "model"))
 ctx1 = ShardingCtx(mesh=mesh1)
 
 # ---- 1. sharded vs single-device LM train step ----
@@ -66,7 +66,7 @@ print("OK sharded==single LM+MoE train step")
 import tempfile
 d = tempfile.mkdtemp()
 checkpoint.save(d, st8, step=1, async_write=False)
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+mesh_b = make_mesh((2, 4), ("data", "model"))
 ctx_b = ShardingCtx(mesh=mesh_b)
 sh = steps.state_shardings(st8, "lm", ctx_b)
 sh = steps.fit_tree(jax.eval_shape(lambda: st8), sh, mesh_b)
@@ -114,6 +114,7 @@ def test_multidevice_semantics(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # fake CPU devices by design; never the chip
     res = subprocess.run(
         [sys.executable, "-c", SCRIPT], env=env, capture_output=True, text=True, timeout=1200
     )

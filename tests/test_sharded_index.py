@@ -22,7 +22,7 @@ import pytest
 from repro import index as ix
 from repro.core.cdf import true_ranks
 from repro.dist import sharded_index as si
-from repro.dist.sharding import ShardingCtx
+from repro.dist.sharding import ShardingCtx, make_mesh
 from repro.index import registry
 
 from conftest import make_table, make_queries
@@ -53,7 +53,7 @@ def _mesh_ctx(n_shards):
     does not have enough devices."""
     if len(jax.devices()) < n_shards:
         return None
-    mesh = jax.make_mesh((1, n_shards), ("data", "model"))
+    mesh = make_mesh((1, n_shards), ("data", "model"))
     return ShardingCtx(mesh=mesh)  # tp_fsdp: tp -> model
 
 
@@ -66,7 +66,7 @@ def test_sharding_ctx_n_resolved_product():
     """n() returns the resolved product over every mesh axis a logical
     axis occupies — including size-1-padded axes — and normalises
     string-valued rules instead of iterating their characters."""
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     ctx = ShardingCtx(mesh=mesh)
     assert ctx.mesh_axes("dp") == ("pod", "data")
     assert ctx.n("dp") == 1  # 1 * 1, both axes resolved
@@ -316,7 +316,7 @@ from repro import index as ix
 from repro.core import as_table
 from repro.core.cdf import true_ranks
 from repro.dist import sharded_index as si
-from repro.dist.sharding import ShardingCtx
+from repro.dist.sharding import ShardingCtx, make_mesh
 
 assert len(jax.devices()) == 4
 rng = np.random.default_rng(5)
@@ -329,7 +329,7 @@ qs = np.concatenate([
 want = true_ranks(table, qs)
 
 for n_shards, mesh_shape in ((2, (2, 2)), (4, (1, 4))):
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     ctx = ShardingCtx(mesh=mesh, rules={"tp": ("model",) if n_shards != 4 else ("data", "model")})
     assert ctx.n("tp") == n_shards, (ctx.n("tp"), n_shards)
     for kind, params in [("RMI", dict(b=64)), ("PGM", dict(eps=32)), ("BTREE", dict(fanout=8))]:
@@ -338,11 +338,17 @@ for n_shards, mesh_shape in ((2, (2, 2)), (4, (1, 4))):
             got = np.asarray(si.sharded_lookup(
                 sidx, qs, ctx, mode=mode, cap_factor=float(n_shards)))
             assert np.array_equal(got, want), (kind, n_shards, mode)
+    # place(): each tp device holds its own shard row; answers unchanged
+    placed = sidx.place(ctx)
+    rows = {s.device: s.data.shape for s in placed.tables.addressable_shards}
+    assert len(rows) == 4 and all(r[0] == 1 for r in rows.values()), rows
+    got = np.asarray(si.sharded_lookup(placed, qs, ctx, mode="a2a", cap_factor=float(n_shards)))
+    assert np.array_equal(got, want), ("placed", n_shards)
     print(f"OK {n_shards}-way a2a+allgather")
 
 # donated refresh under the 4-way mesh: swap shard 1, results track the new tier
 from repro.index import registry
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+mesh = make_mesh((1, 4), ("data", "model"))
 ctx = ShardingCtx(mesh=mesh)
 sidx = si.ShardedIndex.build("RMI", table, n_shards=4, b=64)
 m = int(sidx.tables.shape[1])
@@ -375,6 +381,7 @@ def test_sharded_collectives_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # fake CPU devices by design; never the chip
     res = subprocess.run(
         [sys.executable, "-c", SCRIPT], env=env, capture_output=True, text=True, timeout=1200
     )
