@@ -177,38 +177,46 @@ def _record_tier_metrics(
     sink: dict | None = None,
     label: str | None = None,
 ) -> None:
+    """One telemetry-on call's routing counters.  In a profiler trace the
+    host spans ``tier.telemetry.pull`` (the two device pulls, which wait
+    for the lookup) and ``tier.telemetry.record`` (registry and sink
+    writes) mark its two halves, inside ``tier.telemetry``."""
     from repro import obs
 
-    hist = np.asarray(_owner_histogram(sidx.fences, queries, sidx.n_shards))
-    b = int(hist.sum())
-    even = b / sidx.n_shards
-    imb = float(hist.max() / even) if even > 0 else 0.0
-    dropped = int(np.asarray(out == DROPPED).sum())
-    tiers = [_ALL_TIERS] if label is None else [_ALL_TIERS, str(label)]
-    for t in tiers:
-        obs.metric("route_lookups").inc(tier=t)
-        obs.metric("route_queries").inc(b, tier=t)
-        obs.metric("route_dropped").inc(dropped, tier=t)
-        obs.metric("route_max").inc(int(hist.max()), tier=t)
-        obs.metric("route_even").inc(even, tier=t)
-        obs.metric("route_imbalance_last").set(imb, tier=t)
-        obs.metric("route_imbalance_peak").max(imb, tier=t)
-    if label is not None:
-        # per-owner-shard histogram, labeled tiers only (the "all" view
-        # would mix tiers of different shard counts): this is the density
-        # estimate weighted_quantile_bounds rebalances from
-        shard_q = obs.metric("route_shard_queries")
-        for s, c in enumerate(hist):
-            if c:
-                shard_q.inc(int(c), tier=str(label), shard=s)
-    if sink is not None:
-        sink["lookups"] += 1
-        sink["queries"] += b
-        sink["dropped"] += dropped
-        sink["routed_max"] += int(hist.max())
-        sink["routed_even"] += even
-        sink["imbalance_last"] = imb
-        sink["imbalance_peak"] = max(sink["imbalance_peak"], imb)
+    with obs.span("tier.telemetry"):
+        with obs.span("tier.telemetry.pull"):
+            hist = np.asarray(_owner_histogram(sidx.fences, queries, sidx.n_shards))
+            dropped = int(np.asarray(out == DROPPED).sum())
+        with obs.span("tier.telemetry.record"):
+            b = int(hist.sum())
+            even = b / sidx.n_shards
+            imb = float(hist.max() / even) if even > 0 else 0.0
+            tiers = [_ALL_TIERS] if label is None else [_ALL_TIERS, str(label)]
+            for t in tiers:
+                obs.metric("route_lookups").inc(tier=t)
+                obs.metric("route_queries").inc(b, tier=t)
+                obs.metric("route_dropped").inc(dropped, tier=t)
+                obs.metric("route_max").inc(int(hist.max()), tier=t)
+                obs.metric("route_even").inc(even, tier=t)
+                obs.metric("route_imbalance_last").set(imb, tier=t)
+                obs.metric("route_imbalance_peak").max(imb, tier=t)
+            if label is not None:
+                # per-owner-shard histogram, labeled tiers only (the "all"
+                # view would mix tiers of different shard counts): this is
+                # the density estimate weighted_quantile_bounds rebalances from
+                shard_q = obs.metric("route_shard_queries")
+                for s, c in enumerate(hist):
+                    if c:
+                        shard_q.inc(int(c), tier=str(label), shard=s)
+            if sink is not None:
+                sink["lookups"] += 1
+                sink["queries"] += b
+                sink["dropped"] += dropped
+                sink["routed_max"] += int(hist.max())
+                sink["routed_even"] += even
+                sink["imbalance_last"] = imb
+                sink["imbalance_peak"] = max(sink["imbalance_peak"], imb)
+
 
 def shard_query_weights(tier: str, n_shards: int) -> np.ndarray:
     """Observed per-owner-shard query counts for one labeled tier, read

@@ -222,12 +222,16 @@ def lookup_impl(index: Index, table, queries, backend: str):
     if backend == "pallas":
         return impl.pallas(index, table, queries)
 
-    lo, hi = impl.intervals(index, table, queries)
+    # named scopes are op metadata only: the device trace attributes each
+    # op to predict or search, and the jitted program keeps its name
+    with jax.named_scope("predict"):
+        lo, hi = impl.intervals(index, table, queries)
     from repro.core import search
 
-    if backend == "bbs":
-        return search.bounded_bbs_branchy(table, queries, lo, hi)
-    return search.bounded_bfs(table, queries, lo, hi, max_window=1 << impl.epi_steps(index))
+    with jax.named_scope("search"):
+        if backend == "bbs":
+            return search.bounded_bbs_branchy(table, queries, lo, hi)
+        return search.bounded_bfs(table, queries, lo, hi, max_window=1 << impl.epi_steps(index))
 
 
 def batched_pallas_impl(index: Index, tables, queries):

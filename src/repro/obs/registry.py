@@ -164,8 +164,6 @@ CATALOGUE: tuple = (
      "occupied batch slots"),
     ("lookup_latency_us", "histogram", ("kind", "backend", "tier", "phase"),
      "timed_lookup latency: phase=host (dispatch returned) / device (block_until_ready)"),
-    ("span_us", "histogram", ("name",),
-     "host wall-time of span(name) blocks"),
 )
 
 

@@ -1,10 +1,10 @@
 """Spans, stopwatches, and the latency-histogram lookup wrapper.
 
-* :func:`span` — ``with span("name"):`` records host wall-time into the
-  ``span_us`` histogram (host-side observe: zero device dispatches).
-  When ``REPRO_PROFILE=<dir>`` is set, the *outermost* span additionally
-  brackets its body with ``jax.profiler.start_trace``/``stop_trace`` so
-  Pallas kernels and XLA ops land in a TensorBoard-readable trace.
+* :func:`span` — ``with span("name"):`` marks the block as a
+  ``jax.profiler.TraceAnnotation``: under an active profiler trace it
+  lands on the host plane of the same ``.xplane.pb`` as the device ops,
+  on one clock; with no trace active it is a C++ no-op.  Records nothing
+  into the registry.
 * :func:`stopwatch` — the sanctioned way to take a wall-clock delta in
   ``src/repro/`` (analyzer rule R8 flags raw ``time.perf_counter()``
   subtraction outside ``repro.obs``): ``sw = stopwatch(); ...;
@@ -19,9 +19,7 @@
 
 from __future__ import annotations
 
-import os
 import time
-from contextlib import contextmanager
 
 from . import registry as _registry
 
@@ -56,38 +54,18 @@ def stopwatch() -> Stopwatch:
     return Stopwatch()
 
 
-_SPAN_DEPTH = 0  # outermost-span detection for the profiler bracket
+def span(name: str):
+    """A host span named ``name`` in the profiler's trace.
 
-
-@contextmanager
-def span(name: str, *, registry: "_registry.Registry | None" = None):
-    """Record the block's host wall-time into ``span_us{name=...}``.
-
-    Nested spans each record their own time; only the outermost span
-    starts/stops the optional ``jax.profiler`` trace
-    (``REPRO_PROFILE=<dir>``), so a profiled serving step yields one
-    coherent trace file rather than one per nested span.
+    ``with span("tier.telemetry"): ...`` enters
+    ``jax.profiler.TraceAnnotation(name)``.  Spans nest by time on their
+    thread: the parent of a span is the span that encloses it.  Nothing
+    is recorded when no profiler trace is active, and the span never
+    starts one.
     """
-    global _SPAN_DEPTH
-    reg = registry or _registry.default_registry()
-    prof_dir = os.environ.get("REPRO_PROFILE")
-    profiling = bool(prof_dir) and _SPAN_DEPTH == 0
-    if profiling:
-        import jax
+    from jax import profiler
 
-        jax.profiler.start_trace(prof_dir)
-    _SPAN_DEPTH += 1
-    sw = Stopwatch()
-    try:
-        yield sw
-    finally:
-        elapsed_us = sw.elapsed * 1e6
-        _SPAN_DEPTH -= 1
-        if profiling:
-            import jax
-
-            jax.profiler.stop_trace()
-        reg.metric("span_us").observe(elapsed_us, name=name)
+    return profiler.TraceAnnotation(name)
 
 
 def _target_kind(target) -> str:

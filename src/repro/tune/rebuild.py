@@ -52,7 +52,6 @@ import numpy as np
 
 from repro.dist.sharded_index import (
     ShardedIndex,
-    _fresh_tier_metrics,
     _tier_counters_from_obs,
     compact_shard,
     derived_tier_metrics,
@@ -178,7 +177,6 @@ class TunedTier:
         #: process keep separate tier_*/route_* counter labelsets
         self.name = name or f"tier{next(_TIER_IDS)}"
         self.counters = _Counters(self.name)
-        self._routing = _fresh_tier_metrics()  # legacy dict sink (kept in step)
         #: staleness epoch: bumped on every state change that can alter
         #: served answers (insert/compact/refresh/restack/rebalance).
         #: Derived read structures (repro.serve.hotcache.HotKeyCache)
@@ -198,14 +196,14 @@ class TunedTier:
     # -- serving path ------------------------------------------------------
     def lookup(self, queries, **kw):
         """Tier lookup with telemetry on (imbalance/drop counters,
-        attributed to this tier's own sink as well as the global view).
+        attributed to this tier's own ``route_*`` label as well as the
+        global view).
         When the policy enables rebalancing, each lookup also feeds the
         drift window (:meth:`maybe_rebalance`) — answers are computed
         against the pre-rebalance fences, so the batch that trips the
         threshold is still served exactly."""
         self.counters.lookups += 1
         kw.setdefault("telemetry", True)
-        kw.setdefault("telemetry_sink", self._routing)
         kw.setdefault("telemetry_label", self.name)
         kw.setdefault("backend", self.policy.backend)
         out = sharded_lookup(self.sidx, queries, self.ctx, **kw)

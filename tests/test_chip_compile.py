@@ -11,6 +11,7 @@ compile-cache helper's directory choice.
 
 import importlib.util
 import os
+import re
 from pathlib import Path
 
 import numpy as np
@@ -82,6 +83,27 @@ def test_xla_lookup_compiles_at_sosd_size(one_chip, small_table, kind, params):
     lookup = jax.jit(ix.lookup_impl, static_argnames="backend")
     compiled = lookup.lower(_shapes(idx, one_chip), table, q, backend="xla").compile()
     assert _fits_one_chip(compiled) >= SOSD_KEYS * 8
+
+
+def _op_names(hlo_text: str, pattern: str) -> list:
+    """``op_name`` metadata of the instructions whose line matches ``pattern``."""
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in hlo_text.splitlines() if re.search(pattern, line)]
+
+
+def test_sy_rmi_lookup_scopes_at_sosd_size(one_chip, small_table):
+    """The attribution the device trace relies on, in the program the
+    chip runs: the bounded search's loop sits under ``search``, and the
+    u64 table's limb split (``X64SplitHigh``/``Low`` of the table
+    parameter) under neither scope."""
+    idx = ix.build("SY-RMI", small_table, space_pct=0.05)
+    table = jax.ShapeDtypeStruct((SOSD_KEYS,), jnp.uint64, sharding=one_chip)
+    q = jax.ShapeDtypeStruct((16_384,), jnp.uint64, sharding=one_chip)
+    text = ix.index._lookup_jit.lower(_shapes(idx, one_chip), table, q, backend="xla").compile().as_text()
+    whiles = _op_names(text, r"^\s*(ROOT )?%while[.\d]* = ")
+    assert whiles and all("/search/" in n for n in whiles), whiles
+    splits = _op_names(text, rf"= u32\[{SOSD_KEYS}\].*custom_call_target=\"X64Split(High|Low)\"")
+    assert sorted(splits) == ["table", "table"], splits
 
 
 def test_vmapped_tier_compiles_at_sosd_size(one_chip, small_table):

@@ -14,6 +14,7 @@ import pytest
 
 from repro import index as ix
 from repro.core.cdf import true_ranks
+from repro.index.impls import query_impl
 from repro.data import distributions
 
 from conftest import make_table, make_queries
@@ -212,3 +213,23 @@ def test_info_metadata_passthrough(rng):
     _, treedef = jax.tree_util.tree_flatten(idx)
     idx2 = jax.tree_util.tree_unflatten(treedef, jax.tree_util.tree_flatten(idx)[0])
     assert idx2.info == {}  # metadata intentionally dropped
+
+
+# ---------------------------------------------------------------------------
+# Named scopes: the device trace tells model predict from bounded search
+# ---------------------------------------------------------------------------
+
+INTERVAL_KINDS = [k for k in SPEC_PER_KIND if query_impl(k).lookup is None]
+
+
+@pytest.mark.parametrize("kind", INTERVAL_KINDS)
+def test_lookup_ops_carry_predict_and_search_scopes(kind):
+    """Every kind that answers through ``intervals`` + bounded search
+    lowers its predict ops under ``predict/`` and its search ops under
+    ``search/``, inside the one ``_lookup_jit`` program."""
+    table = make_table(np.random.default_rng(21), "uniform", 4000)
+    idx = ix.build(SPEC_PER_KIND[kind], table)
+    lowered = ix.index._lookup_jit.lower(idx, table, table[:64], backend="xla")
+    text = lowered.as_text(debug_info=True)
+    assert "jit(_lookup_jit)/predict/" in text, kind
+    assert "jit(_lookup_jit)/search/" in text, kind

@@ -36,6 +36,7 @@ from conftest import make_queries, make_table
 
 ROOT = Path(__file__).resolve().parents[1]
 N = 2048
+LATENCY_LABELS = {"kind": "RMI", "backend": "xla", "tier": "obs_test", "phase": "host"}
 
 
 def fresh_registry() -> obs_registry.Registry:
@@ -124,7 +125,7 @@ def test_snapshot_diff_counters_subtract_gauges_latch():
 def test_jsonl_round_trip_is_stable():
     reg = fresh_registry()
     reg.metric("route_queries").inc(4, tier="a")
-    reg.metric("span_us").observe(5.0, name="x")
+    reg.metric("lookup_latency_us").observe(5.0, **LATENCY_LABELS)
     snap = reg.snapshot()
     text = obs.to_jsonl(snap)
     for line in text.strip().splitlines():
@@ -132,7 +133,7 @@ def test_jsonl_round_trip_is_stable():
         assert {"name", "type", "labels"} <= set(row)
     back = obs.from_jsonl(text)
     assert obs.sample_value(back, "route_queries", tier="a") == 4.0
-    assert obs.find_sample(back, "span_us", name="x")["count"] == 1
+    assert obs.find_sample(back, "lookup_latency_us", **LATENCY_LABELS)["count"] == 1
     assert obs.to_jsonl(back) == text
 
 
@@ -146,14 +147,64 @@ def test_reset_prefix_only_clears_that_family():
     assert obs.sample_value(snap, "tier_lookups", tier="a") == 2.0
 
 
-def test_span_and_stopwatch_record():
-    reg = fresh_registry()
+def traced_spans(trace_dir: Path, body, names: tuple) -> dict:
+    """Run ``body`` under a CPU profiler trace and read back the host
+    spans named ``names`` from its ``.xplane.pb``: name -> [(start, end)]."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = trace_dir.glob("plugins/profile/*/*.xplane.pb")
+    spans = {n: [] for n in names}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in spans:
+                    spans[e.name].append((e.start_ns, e.end_ns))
+    return spans
+
+
+def test_span_and_stopwatch_record(tmp_path):
+    """A span is a profiler annotation: nested spans land in the trace,
+    the child inside its parent; outside a trace a span records nothing."""
+    before = obs.snapshot()
     sw = stopwatch()
-    with span("obs_test.block", registry=reg):
+    with span("obs_test.untraced"):  # no trace active: a no-op
         pass
-    assert sw.elapsed >= 0.0
-    s = obs.find_sample(reg.snapshot(), "span_us", name="obs_test.block")
-    assert s["count"] == 1
+
+    def body():
+        with span("obs_test.outer"):
+            with span("obs_test.inner"):
+                sw.restart()
+                while sw.elapsed < 1e-3:
+                    pass
+
+    got = traced_spans(tmp_path, body, ("obs_test.outer", "obs_test.inner", "obs_test.untraced"))
+    (outer,), (inner,) = got["obs_test.outer"], got["obs_test.inner"]
+    assert outer[0] <= inner[0] < inner[1] <= outer[1]
+    assert inner[1] - inner[0] >= 1e6  # ns: the busy millisecond
+    assert got["obs_test.untraced"] == []
+    assert obs.diff(before, obs.snapshot()) == obs.diff(before, before)  # no registry write
+
+
+def test_tier_lookup_writes_its_telemetry_spans(tmp_path):
+    """One ``TunedTier.lookup`` writes ``tier.telemetry`` once, holding
+    one ``tier.telemetry.pull`` followed by one ``tier.telemetry.record``."""
+    from repro.tune.rebuild import TunedTier
+
+    rng = np.random.default_rng(14)  # not the session stream: leaves other tests' tables alone
+    table = make_table(rng, "uniform", N)
+    qs = make_queries(rng, table, 256)
+    tier = TunedTier(table, n_shards=4, spec=ix.RMISpec(b=64))
+    tier.lookup(qs)  # compile outside the trace
+    names = ("tier.telemetry", "tier.telemetry.pull", "tier.telemetry.record")
+    got = traced_spans(tmp_path, lambda: np.asarray(tier.lookup(qs)), names)
+    (tel,), (pull,), (rec,) = (got[n] for n in names)
+    assert tel[0] <= pull[0] < pull[1] <= rec[0] < rec[1] <= tel[1]
 
 
 # ---------------------------------------------------------------------------
@@ -264,6 +315,7 @@ def test_tuned_tier_metrics_render_from_snapshot(rng):
     from repro.index import RMISpec
     from repro.tune.rebuild import RebuildPolicy, TunedTier
 
+    rng = np.random.default_rng(14)  # not the session stream: leaves other tests' tables alone
     table = make_table(rng, "uniform", N)
     qs = make_queries(rng, table, 256)
     tier = TunedTier(table, n_shards=2, policy=RebuildPolicy(), spec=RMISpec(b=64))
@@ -363,7 +415,7 @@ def test_serve_slo_absolute_gates():
 def test_obs_cli_dump_and_diff(tmp_path):
     reg = fresh_registry()
     reg.metric("route_queries").inc(4, tier="a")
-    reg.metric("span_us").observe(5.0, name="x")
+    reg.metric("lookup_latency_us").observe(5.0, **LATENCY_LABELS)
     before = tmp_path / "before.jsonl"
     before.write_text(obs.to_jsonl(reg.snapshot()))
     reg.metric("route_queries").inc(6, tier="a")
@@ -377,7 +429,7 @@ def test_obs_cli_dump_and_diff(tmp_path):
         capture_output=True, text=True, env=env, cwd=ROOT,
     )
     assert dump.returncode == 0, dump.stderr
-    assert "route_queries" in dump.stdout and "span_us" in dump.stdout
+    assert "route_queries" in dump.stdout and "lookup_latency_us" in dump.stdout
     d = subprocess.run(
         [sys.executable, "-m", "repro.obs", "diff", str(before), str(after)],
         capture_output=True, text=True, env=env, cwd=ROOT,

@@ -5,8 +5,8 @@ PR 8 unified telemetry behind ``repro.obs``: latency measured with ad-hoc
 histogram, no snapshot, no SLO gate, and silently diverges from the
 distributions the bench-trend baselines assert on.  Library code takes
 wall-clock deltas through ``repro.obs.timing`` instead: ``stopwatch()``
-for build-time accounting, ``span("name")`` for traced blocks,
-``timed_lookup`` for lookup latency.
+for build-time accounting, ``timed_lookup`` for lookup latency
+(``span("name")`` marks a block in a profiler trace and measures nothing).
 
 Scope: ``src/repro/`` only, minus ``src/repro/obs/`` (the one place the
 raw clock is allowed — it *implements* the stopwatch).  ``benchmarks/``
@@ -29,7 +29,7 @@ from .framework import AstRule, Module
 _TIMER_ATTRS = frozenset({"perf_counter", "perf_counter_ns", "time", "monotonic", "monotonic_ns"})
 _HINT = (
     "take deltas through repro.obs.timing — stopwatch().elapsed for build "
-    "accounting, span()/timed_lookup() for serving latency — so they land "
+    "accounting, timed_lookup() for serving latency — so they land "
     "in the registry histograms"
 )
 
