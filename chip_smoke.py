@@ -166,7 +166,10 @@ def four_chips(table, batches, wants, devices) -> None:
         jax.block_until_ready(sidx)
         build_s = sw.elapsed
         # every stacked leaf is split by shard: each chip must hold a quarter
-        stacked = [sidx.tables, sidx.counts, sidx.offsets, *jax.tree_util.tree_leaves(sidx.index)]
+        stacked = [
+            *jax.tree_util.tree_leaves(sidx.tables), sidx.counts, sidx.offsets,
+            *jax.tree_util.tree_leaves(sidx.index),
+        ]
         tier_bytes = sum(int(x.nbytes) for x in stacked)
         held = {d.id: 0 for d in ctx.mesh.devices.flat}
         for x in stacked:

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .cdf import ceil_log2
+from .limbs import LimbTable, keys_of
 
 #: Predecessor rank reported when ``q`` is smaller than every key —
 #: ``rank(x) - 1`` for rank 0.  Every search procedure and index kind
@@ -38,6 +39,8 @@ NO_PRED = -1
 
 
 def _take(table, idx):
+    if isinstance(table, LimbTable):
+        return table.take(idx)
     return jnp.take(table, idx, mode="clip")
 
 
@@ -47,8 +50,10 @@ def bounded_upper_bound(table, q, lo, length, *, steps: int):
     Branch-free: exactly ``steps`` iterations of the Khuong–Morin loop
     (supplementary Algorithm 1) with ``<=`` comparisons, vectorised over
     queries.  ``steps`` must be >= ceil(log2(max length)).
-    Zero-length windows return ``lo``.
+    Zero-length windows return ``lo``.  ``table`` is a u64 array or a
+    :class:`~repro.core.limbs.LimbTable`, whose compare is by limbs.
     """
+    q = keys_of(table, q)
     base = lo.astype(jnp.int64)
     n = length.astype(jnp.int64)
 
@@ -97,12 +102,14 @@ def bounded_bbs_branchy(table, q, lo, hi):
     all lanes iterate until every lane has converged — the vectorised
     semantics of the paper's scalar branchy loop.  Shared by the
     ``backend="bbs"`` path of every :class:`repro.index.Index` kind.
+    ``table`` is a u64 array or a :class:`~repro.core.limbs.LimbTable`.
     """
     n = table.shape[0]
     res0 = jnp.full(q.shape, NO_PRED, dtype=jnp.int64)
     active0 = jnp.ones(q.shape, dtype=bool)
     lo = jnp.clip(lo.astype(jnp.int64), 0, n - 1)
     hi = jnp.clip(hi.astype(jnp.int64), 0, n - 1)
+    qk = keys_of(table, q)
 
     def cond(state):
         return jnp.any(state[3])
@@ -111,9 +118,9 @@ def bounded_bbs_branchy(table, q, lo, hi):
         lo, hi, res, active = state
         mid = (lo + hi) >> 1
         v = _take(table, mid)
-        found = active & (v == q)
+        found = active & (v == qk)
         res = jnp.where(found, mid, res)
-        go_right = v < q
+        go_right = v < qk
         lo_n = jnp.where(active & go_right, mid + 1, lo)
         hi_n = jnp.where(active & ~go_right, mid - 1, hi)
         res = jnp.where(active & ~found & (lo_n > hi_n), hi_n, res)

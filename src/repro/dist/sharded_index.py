@@ -48,6 +48,11 @@ the last key (a clamp against the per-shard valid count restores exact
 ranks), and variable-length index leaves reuse the power-of-two sentinel
 padding idiom of :mod:`repro.index.impls`.
 
+The stacked tables stay resident as u32 limb planes
+(:class:`~repro.core.limbs.LimbTable`), split on the host when a row is
+written: a u64 table operand would be split whole into limbs at the
+entry of every lookup program, on a chip with no 64-bit integer unit.
+
 Rebuilds swap in without host round-trips: :func:`refresh_shard` donates
 the old stacked pytree to a jitted ``.at[shard].set`` update
 (``donate_argnums=0``), recomputing offsets on device.
@@ -65,8 +70,16 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core.cdf import POS_DTYPE
+from repro.core.limbs import LimbTable
 from repro.core.search import NO_PRED
-from repro.index import Index, batched_pallas_impl, count_trace, lookup_impl, registry
+from repro.index import (
+    Index,
+    batched_pallas_impl,
+    count_trace,
+    count_u64_table,
+    lookup_impl,
+    registry,
+)
 from repro.index.specs import IndexSpec
 
 from . import collectives
@@ -411,8 +424,10 @@ class ShardedIndex:
     Attributes
     ----------
     index:   stacked :class:`Index` — every leaf has leading shard axis.
-    tables:  ``(n_shards, m)`` uint64 — per-shard sorted tables, padded
-             to a common power-of-two ``m`` (strictly increasing pad).
+    tables:  ``(n_shards, m)`` :class:`~repro.core.limbs.LimbTable` —
+             per-shard sorted uint64 tables as two u32 limb planes,
+             padded to a common power-of-two ``m`` (strictly increasing
+             pad); ``np.asarray(tables[s])`` reads row ``s`` as uint64.
     fences:  ``(n_shards,)`` uint64 — first key of each shard; the
              router searches ``fences[1:]``.
     counts:  ``(n_shards,)`` int64 — valid (unpadded) keys per shard.
@@ -544,7 +559,7 @@ class ShardedIndex:
         info = {"spec": spec.display_name(), "n": n, "m": m}
         return ShardedIndex(
             index=stacked,
-            tables=jnp.asarray(np.stack(padded)),
+            tables=LimbTable.from_u64(np.stack(padded)),
             fences=jnp.asarray(fences),
             counts=jnp.asarray(counts),
             offsets=jnp.asarray(offsets),
@@ -574,7 +589,7 @@ class ShardedIndex:
         with np.load(path) as z:
             meta = json.loads(bytes(z["__meta__"]).decode())
             arrays = {k[len("idx_") :]: jnp.asarray(z[k]) for k in z.files if k.startswith("idx_")}
-            tables = jnp.asarray(z["tables"])
+            tables = LimbTable.from_u64(z["tables"])
             fences = jnp.asarray(z["fences"])
             counts = jnp.asarray(z["counts"])
             offsets = jnp.asarray(z["offsets"])
@@ -647,6 +662,7 @@ def _answer_local(local_index: Index, local_table, count, offset, queries, backe
 @partial(jax.jit, static_argnames=("backend",))
 def _lookup_vmapped(sidx: ShardedIndex, queries, backend: str):
     count_trace(f"sharded:{sidx.kind}", f"ref:{backend}")
+    count_u64_table("tier", sidx.tables)
     owners = route_owners(sidx.fences, queries)
 
     if backend == "pallas":
@@ -673,6 +689,7 @@ def _lookup_vmapped(sidx: ShardedIndex, queries, backend: str):
 @partial(jax.jit, static_argnames=("mesh", "axes", "backend", "cap"))
 def _lookup_a2a(sidx: ShardedIndex, queries, mesh, axes, backend: str, cap: int):
     count_trace(f"sharded:{sidx.kind}", f"a2a:{backend}")
+    count_u64_table("tier", sidx.tables)
     n_shards = sidx.n_shards
     ax = axes if len(axes) > 1 else axes[0]
 
@@ -704,6 +721,7 @@ def _lookup_a2a(sidx: ShardedIndex, queries, mesh, axes, backend: str, cap: int)
 @partial(jax.jit, static_argnames=("mesh", "axes", "backend"))
 def _lookup_allgather(sidx: ShardedIndex, queries, mesh, axes, backend: str):
     count_trace(f"sharded:{sidx.kind}", f"allgather:{backend}")
+    count_u64_table("tier", sidx.tables)
     ax = axes if len(axes) > 1 else axes[0]
 
     def block(idx, tab, cnt, off, fences, q):
@@ -848,7 +866,7 @@ def _install_shard(sidx: ShardedIndex, new_arrays, new_table, new_fence, new_cou
     offsets = jnp.concatenate([jnp.zeros((1,), POS_DTYPE), jnp.cumsum(counts)[:-1]])
     return ShardedIndex(
         index=Index(sidx.index.kind, sidx.index.static, arrays),
-        tables=sidx.tables.at[shard].set(new_table),
+        tables=sidx.tables.set_row(shard, new_table),
         fences=sidx.fences.at[shard].set(new_fence),
         counts=counts,
         offsets=offsets,
@@ -897,7 +915,7 @@ def refresh_shard(sidx: ShardedIndex, shard: int, new_index: Index, new_table) -
     # the rebuilt key set must stay inside this shard's fence slot, or
     # global ranks would silently go wrong for every later shard
     if shard > 0:
-        prev_last = np.uint64(sidx.tables[shard - 1, int(sidx.counts[shard - 1]) - 1])
+        prev_last = np.uint64(np.asarray(sidx.tables[shard - 1, int(sidx.counts[shard - 1]) - 1]))
         if new_table[0] <= prev_last:
             raise ValueError(
                 f"rebuilt shard {shard} starts at {new_table[0]}, inside the previous "
@@ -910,7 +928,7 @@ def refresh_shard(sidx: ShardedIndex, shard: int, new_index: Index, new_table) -
                 f"rebuilt shard {shard} ends at {new_table[-1]}, at or beyond the next "
                 f"shard's fence {next_fence}"
             )
-    padded_tab = jnp.asarray(_pad_sorted_table(new_table, m))
+    padded_tab = LimbTable.from_u64(_pad_sorted_table(new_table, m))
     if sidx.index.kind == "GAPPED":
         # inert zero-count leaf rows, not the generic edge-replication pad
         new_index = _pad_gapped_leaves(new_index, int(sidx.index.arrays["keys"].shape[1]))

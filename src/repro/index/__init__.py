@@ -35,9 +35,11 @@ from .index import (
     batched_pallas_impl,
     build,
     count_trace,
+    count_u64_table,
     lookup_impl,
     reset_trace_counts,
     trace_counts,
+    u64_table_traces,
 )
 from .mutation import InsertReport, NeedsRebuild, updatable_kinds
 from .registry import entry, kinds, spec_for
@@ -62,9 +64,11 @@ __all__ = [
     "batched_pallas_impl",
     "build",
     "count_trace",
+    "count_u64_table",
     "lookup_impl",
     "trace_counts",
     "reset_trace_counts",
+    "u64_table_traces",
     "entry",
     "kinds",
     "spec_for",

@@ -42,15 +42,28 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cdf import POS_DTYPE
+from repro.core.limbs import LimbTable
 
 BACKENDS = ("xla", "bbs", "pallas", "ref")
 
 _TRACE_COUNTS: collections.Counter = collections.Counter()
 
+#: lookup programs traced with a u64 table operand, by program
+#: (``index``: ``_lookup_jit``; ``tier``: the sharded tier's lookups)
+_U64_TABLE_TRACES: collections.Counter = collections.Counter({"index": 0, "tier": 0})
+
 
 def trace_counts() -> dict:
     """(kind, backend) -> number of times the shared lookup was traced."""
     return dict(_TRACE_COUNTS)
+
+
+def u64_table_traces() -> dict:
+    """program -> lookup traces whose table operand was a u64 array, which
+    XLA splits whole into u32 limbs on every call (a
+    :class:`~repro.core.limbs.LimbTable` operand is not counted).  Mirrored
+    into the ``lookup_u64_table_traces`` obs counter at snapshot time."""
+    return dict(_U64_TABLE_TRACES)
 
 
 def reset_trace_counts() -> None:
@@ -218,7 +231,9 @@ def lookup_impl(index: Index, table, queries, backend: str):
         # keys, so the answer ignores ``table`` on every backend
         return impl.lookup(index, table, queries, backend)
     if backend == "ref":
-        return jnp.searchsorted(table, queries, side="right").astype(POS_DTYPE) - 1
+        # the oracle: a limb table is combined into u64 keys in the program
+        keys = table.combine() if isinstance(table, LimbTable) else table
+        return jnp.searchsorted(keys, queries, side="right").astype(POS_DTYPE) - 1
     if backend == "pallas":
         return impl.pallas(index, table, queries)
 
@@ -258,9 +273,17 @@ def count_trace(kind: str, backend: str) -> None:
     _TRACE_COUNTS[(kind, backend)] += 1
 
 
+def count_u64_table(program: str, table) -> None:
+    """Record, at trace time like :func:`count_trace`, that ``program``
+    was traced with a u64 table operand (see :func:`u64_table_traces`)."""
+    if getattr(table, "dtype", None) == jnp.uint64:
+        _U64_TABLE_TRACES[program] += 1
+
+
 @partial(jax.jit, static_argnames=("backend",))
 def _lookup_jit(index: Index, table, queries, backend: str):
     count_trace(index.kind, backend)  # python side effect: runs per trace
+    count_u64_table("index", table)
     return lookup_impl(index, table, queries, backend)
 
 

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cdf import ceil_log2
+from repro.core.limbs import LimbTable
 
 from .rmi_search import DEFAULT_TILE_Q
 from .kary_search import kary_search_pallas, LANES
@@ -33,7 +34,11 @@ def _interpret() -> bool:
 
 
 def split_u64(x_u64: np.ndarray):
-    """uint64 -> (hi, lo) uint32 limbs (host or device arrays)."""
+    """uint64 -> (hi, lo) uint32 limbs (host or device arrays).  A
+    :class:`~repro.core.limbs.LimbTable` is split already: its planes
+    are returned as they are."""
+    if isinstance(x_u64, LimbTable):
+        return x_u64.hi, x_u64.lo
     x = jnp.asarray(x_u64, dtype=jnp.uint64)
     hi = (x >> jnp.uint64(32)).astype(jnp.uint32)
     lo = (x & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)

@@ -64,9 +64,11 @@ __all__ = [
 
 
 def _collect_index_traces(reg: Registry) -> None:
-    """Mirror ``repro.index.trace_counts()`` into ``index_traces`` gauges
-    at snapshot time.  Polls ``sys.modules`` only — never forces the
-    index machinery in just to report that it was never used."""
+    """Mirror ``repro.index.trace_counts()`` into ``index_traces`` gauges,
+    and ``repro.index.u64_table_traces()`` into the
+    ``lookup_u64_table_traces`` counter, at snapshot time.  Polls
+    ``sys.modules`` only — never forces the index machinery in just to
+    report that it was never used."""
     ix = sys.modules.get("repro.index")
     if ix is None:
         return
@@ -74,6 +76,9 @@ def _collect_index_traces(reg: Registry) -> None:
     g.clear()  # trace counts can reset (reset_trace_counts); gauges follow
     for (kind, backend), n in ix.trace_counts().items():
         g.set(float(n), kind=kind, backend=backend)
+    c = reg.metric("lookup_u64_table_traces")
+    for program, n in ix.u64_table_traces().items():
+        c.set_value(float(n), program=program)
 
 
 register_collector(_collect_index_traces)

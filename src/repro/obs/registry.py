@@ -84,6 +84,9 @@ def exp_edges(lo: float, hi: float, n: int) -> tuple:
 CATALOGUE: tuple = (
     ("index_traces", "gauge", ("kind", "backend"),
      "jitted lookup traces per (kind, backend) — mirror of repro.index.trace_counts()"),
+    ("lookup_u64_table_traces", "counter", ("program",),
+     "lookup traces with a u64 table operand, split whole on every call (program=index | tier)"
+     " — mirror of repro.index.u64_table_traces()"),
     ("route_lookups", "counter", ("tier",),
      "telemetry-enabled sharded_lookup calls"),
     ("route_queries", "counter", ("tier",),

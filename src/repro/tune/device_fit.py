@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cdf import POS_DTYPE, bit_length_device, ceil_log2_device, segment_ids
+from repro.core.limbs import LimbTable
 from repro.core.pgm import FAST_CHUNK, pgm_device_slopes, pgm_fit_fast, pgm_segments_scan
 from repro.core.radix_spline import rs_knots_fast, rs_knots_scan, rs_verified_eps
 from repro.dist.sharded_index import ShardedIndex
@@ -274,7 +275,7 @@ def _device_refresh_impl(
 
     # fence discipline, on device (same checks refresh_shard raises for)
     if shard > 0:
-        prev_last = jnp.take(sidx.tables[shard - 1], sidx.counts[shard - 1] - 1)
+        prev_last = sidx.tables[shard - 1].take(sidx.counts[shard - 1] - 1).combine()
         ok &= jnp.take(row, 0) > prev_last
     if shard + 1 < n_shards:
         ok &= jnp.take(row, count - 1) < sidx.fences[shard + 1]
@@ -290,7 +291,9 @@ def _device_refresh_impl(
     offsets = jnp.concatenate([jnp.zeros((1,), POS_DTYPE), jnp.cumsum(counts)[:-1]])
     out = ShardedIndex(
         index=Index(kind, sidx.index.static, arrays),
-        tables=sidx.tables.at[shard].set(install(padded_tab, sidx.tables[shard])),
+        tables=sidx.tables.set_row(
+            shard, jax.tree.map(install, LimbTable.split(padded_tab), sidx.tables[shard])
+        ),
         fences=sidx.fences.at[shard].set(install(jnp.take(row, 0), sidx.fences[shard])),
         counts=counts,
         offsets=offsets,

@@ -21,6 +21,7 @@ import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro import index as ix
+from repro.core.limbs import LimbTable
 from repro.data.distributions import generate
 from repro.dist import sharded_index as si
 from repro.dist.sharding import ShardingCtx
@@ -68,6 +69,23 @@ def _shapes(tree, sharding):
     )
 
 
+def _limb_planes(shape, sharding) -> LimbTable:
+    """The tier's resident tables as described shapes: two u32 planes."""
+    plane = jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=sharding)
+    return LimbTable(plane, plane)
+
+
+def _large_splits(hlo_text: str, min_elems: int) -> list:
+    """``X64Split`` custom calls whose result holds at least ``min_elems``
+    words: the whole-table limb split of a u64 table operand."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = re.search(r"= u32\[([\d,]+)\][^=]*custom_call_target=\"X64Split", line)
+        if m and int(np.prod([int(d) for d in m.group(1).split(",")])) >= min_elems:
+            out.append(line.strip()[:120])
+    return out
+
+
 def _fits_one_chip(compiled) -> int:
     ma = compiled.memory_analysis()
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes + ma.output_size_in_bytes
@@ -107,11 +125,15 @@ def test_sy_rmi_lookup_scopes_at_sosd_size(one_chip, small_table):
 
 
 def test_vmapped_tier_compiles_at_sosd_size(one_chip, small_table):
+    """The one-chip tier over resident u32 limb planes: the program
+    splits no table-sized operand and needs no table-sized scratch (a
+    u64 table operand costs two whole-table ``X64Split`` calls and about
+    2.15 GB of temporaries on every call)."""
     sidx = si.ShardedIndex.build("PGM_M", small_table, n_shards=4, space_pct=0.05)
     shapes = _shapes(sidx, one_chip)
     tier = si.ShardedIndex(
         shapes.index,
-        jax.ShapeDtypeStruct((4, SHARD_ROWS), jnp.uint64, sharding=one_chip),
+        _limb_planes((4, SHARD_ROWS), one_chip),
         shapes.fences,
         shapes.counts,
         shapes.offsets,
@@ -119,6 +141,8 @@ def test_vmapped_tier_compiles_at_sosd_size(one_chip, small_table):
     q = jax.ShapeDtypeStruct((BATCH,), jnp.uint64, sharding=one_chip)
     compiled = si._lookup_vmapped.lower(tier, q, backend="xla").compile()
     assert _fits_one_chip(compiled) >= 4 * SHARD_ROWS * 8
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert _large_splits(compiled.as_text(), SHARD_ROWS) == []
 
 
 def test_a2a_sharded_lookup_compiles_on_four_chips(topo, small_table):
@@ -133,7 +157,7 @@ def test_a2a_sharded_lookup_compiles_on_four_chips(topo, small_table):
     shapes = _shapes(sidx, by_shard)
     tier = si.ShardedIndex(
         shapes.index,
-        jax.ShapeDtypeStruct((4, SHARD_ROWS), jnp.uint64, sharding=by_shard),
+        _limb_planes((4, SHARD_ROWS), by_shard),
         jax.ShapeDtypeStruct(sidx.fences.shape, sidx.fences.dtype, sharding=NamedSharding(mesh, P())),
         shapes.counts,
         shapes.offsets,
@@ -144,6 +168,9 @@ def test_a2a_sharded_lookup_compiles_on_four_chips(topo, small_table):
     ma = compiled.memory_analysis()  # per device: one shard row, not the tier
     assert ma.argument_size_in_bytes < 2 * SHARD_ROWS * 8
     assert compiled.as_text().count("all-to-all") >= 2
+    # the shard row's limb planes enter as they are: no split, no scratch copy
+    assert ma.temp_size_in_bytes < 64 << 20
+    assert _large_splits(compiled.as_text(), SHARD_ROWS) == []
 
 
 @pytest.mark.parametrize("kind,params", KINDS, ids=[k for k, _ in KINDS])

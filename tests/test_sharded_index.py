@@ -340,8 +340,9 @@ for n_shards, mesh_shape in ((2, (2, 2)), (4, (1, 4))):
             assert np.array_equal(got, want), (kind, n_shards, mode)
     # place(): each tp device holds its own shard row; answers unchanged
     placed = sidx.place(ctx)
-    rows = {s.device: s.data.shape for s in placed.tables.addressable_shards}
-    assert len(rows) == 4 and all(r[0] == 1 for r in rows.values()), rows
+    for plane in (placed.tables.hi, placed.tables.lo):  # the table's two u32 limb planes
+        rows = {s.device: s.data.shape for s in plane.addressable_shards}
+        assert len(rows) == 4 and all(r[0] == 1 for r in rows.values()), rows
     got = np.asarray(si.sharded_lookup(placed, qs, ctx, mode="a2a", cap_factor=float(n_shards)))
     assert np.array_equal(got, want), ("placed", n_shards)
     print(f"OK {n_shards}-way a2a+allgather")
