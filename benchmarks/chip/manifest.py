@@ -8,7 +8,8 @@ metric sits in a file of its own, found by name:
     loops/<loop>.py           problems(params), drive(...): how a mix loads the system
     draws/<draw>.py           problems(params), positions(...): how a mix picks its keys
     entries/<entry>.py        build(config, table) -> Entry: the program's entry point
-    layers/<metric>.py        read(ctx) -> float | None: one per-layer metric
+    layers/<metric>.py        read(ctx) -> float | None: one per-layer metric;
+                              SPANS: the program's host spans it reads
 
 so a later configuration, mix, loop, key draw, entry or metric is new
 files plus new manifest entries, with no edit to a file that is here.
@@ -73,8 +74,13 @@ def problems(root: Path, man: dict | None = None) -> list:
             out.append(f"{m['name']}: unknown source {m['source']!r}")
         if m["moves"] not in e2e:
             out.append(f"{m['name']}: moves unknown metric {m['moves']!r}")
-        if not bench_file(root, "layers", m["name"], ".py").is_file():
+        reader = bench_file(root, "layers", m["name"], ".py")
+        if not reader.is_file():
             out.append(f"{m['name']}: no reader layers/{m['name']}.py")
+            continue
+        spans = getattr(load_module(reader), "SPANS", ())
+        if not (isinstance(spans, tuple) and all(isinstance(n, str) and NAME.match(n) for n in spans)):
+            out.append(f"{m['name']}: SPANS must be a tuple of span names, not {spans!r}")
     for name, c in configs.items():
         path = root / c["file"]
         if not path.is_file():

@@ -19,9 +19,15 @@ anything on any other device).  In one process it:
    answers with the reference (``reference.py``);
 7. prints earlier JSON lines (``phase``), then one result line: the
    end-to-end metrics with ``--trace 0``, the per-layer metrics (read by
-   ``layers/<metric>.py`` from a profiler trace of the window) with
-   ``--trace 1``.  The numbers compared with their limits go last, in
-   the result's ``checks`` and on standard error.
+   ``layers/<metric>.py`` from a profiler trace of the window and the
+   program's ``repro.obs`` counters over it) with ``--trace 1``.  The
+   numbers compared with their limits go last, in the result's
+   ``checks`` and on standard error.
+
+A traced run compiles without the persistent compilation cache: the
+cache keys a program without its op metadata, and a program cached from
+another source would carry no scopes into the trace.  Its set-up time is
+no metric.
 """
 
 from __future__ import annotations
@@ -69,12 +75,16 @@ def require_chips(devices, chips: int) -> None:
         )
 
 
-def compile_cache(root: Path) -> str:
+def compile_cache(root: Path, use: bool = True) -> str | None:
     """JAX's persistent compilation cache: ``JAX_COMPILATION_CACHE_DIR``
     where set, else ``<checkout>/.jax_cache`` (a fixed path: the path is
-    part of the cache key).  Every program is cached, however quick."""
+    part of the cache key).  Every program is cached, however quick.
+    With ``use`` false the cache is neither read nor written: None."""
     import jax
 
+    jax.config.update("jax_enable_compilation_cache", use)
+    if not use:
+        return None
     path = os.environ.get(CACHE_ENV) or str(Path(root).resolve() / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
@@ -136,7 +146,7 @@ class CompileCounter:
 def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = ROOT,
         require_chip: bool = True, entry_module=None, out=sys.stdout) -> dict:
     """One run of one cell; returns the result line's object."""
-    from benchmarks.chip import manifest, reference, traffic
+    from benchmarks.chip import manifest, readers, reference, traffic
     from benchmarks.chip import trace as tracemod
     from benchmarks.chip.peaks import peaks
     from benchmarks.chip.resident import peak_bytes
@@ -152,11 +162,11 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
         peaks(devices[0].device_kind)  # an unknown chip is an error before anything is built
     used = devices[:chips]
     jax.config.update("jax_enable_x64", True)
-    cache = compile_cache(root)
+    cache = compile_cache(root, use=not trace)
     dev = used[0]
     emit(out, "device", platform=dev.platform, kind=dev.device_kind, count=len(devices),
-         jax=jax.__version__, compile_cache=cache,
-         compile_cache_entries=len(os.listdir(cache)) if os.path.isdir(cache) else 0)
+         jax=jax.__version__, compile_cache=cache or "bypassed: a traced run compiles its scopes",
+         compile_cache_entries=len(os.listdir(cache)) if cache and os.path.isdir(cache) else 0)
 
     from benchmarks.chip import data
 
@@ -180,6 +190,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
     if trace:
         jax.profiler.start_trace(trace_dir, profiler_options=profile_options())
     setup_s = time.perf_counter() - T_START
+    if trace:
+        from repro import obs
+
+        counters_before = obs.snapshot()
     compiles.active = True
     try:
         lat, window_s = cell["loop"].drive(entry, batches, seconds, sample,
@@ -188,6 +202,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
         compiles.active = False
         if trace:
             jax.profiler.stop_trace()
+    if trace:
+        counters = readers.counter_increase(counters_before, obs.snapshot())
     peak = peak_bytes(used)
     model_bytes = entry.model_device_bytes()
     emit(out, "setup", entry=entry.name, keys=len(table), seed=seed, setup_s=setup_s, **timings,
@@ -213,9 +229,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
     attempted = len(lat) * batch
 
     if trace:
+        names = tracemod.collected_spans(cell["per_layer"].values())
         try:
-            chips_ev, spans, window = tracemod.load(tracemod.xplane_file(trace_dir))
-            reduced = tracemod.reduce(chips_ev, spans, window) if window else {}
+            chips_ev, spans, window, op_scopes = tracemod.load(tracemod.xplane_file(trace_dir), names)
+            reduced = tracemod.reduce(chips_ev, spans, window, op_scopes, names) if window else {}
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
         per_query = None
@@ -223,16 +240,21 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
             ranks = np.concatenate([want[k] if k in want else reference.predecessor_rank(table, b)
                                     for k, b in enumerate(batches)])
             per_query = entry.needed_bytes(ranks)
+        on_device = "busy_s" in reduced
         ctx = {
             "trace": reduced or None,
+            "counters": counters,
             "requests": len(lat),
             "timings": timings,
             "needed_bytes_per_request": None if per_query is None else float(per_query.mean()) * batch,
-            "peaks": peaks(dev.device_kind) if reduced else None,
+            "peaks": peaks(dev.device_kind) if on_device else None,
         }
         values = {name: mod.read(ctx) for name, mod in cell["per_layer"].items()}
         emit(out, "trace", busy_s=reduced.get("busy_s"), window_s=reduced.get("window_s"),
-             programs=reduced.get("programs"),
+             programs=reduced.get("programs"), scopes=reduced.get("scopes"),
+             unscoped=reduced.get("unscoped"), unscoped_ops=reduced.get("unscoped_ops"),
+             idle_by_span=reduced.get("idle_by_span"), span_s=reduced.get("span_s"),
+             counters=[[n, dict(labels), v] for (n, labels), v in counters.items() if v],
              needed_bytes_per_request=ctx["needed_bytes_per_request"],
              roofline_bound="HBM bandwidth", peaks=ctx["peaks"])
     else:
@@ -250,7 +272,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, root: Path = R
               "memory_peak_bytes": peak}
     result = {"correct": bool(correct), "attempted": attempted, "failed": wrong,
               "metrics": metrics, "device": device}
-    if trace and reduced:
+    if trace and on_device:
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}

@@ -1,11 +1,16 @@
 #!/usr/bin/env python3
-"""Where a traced window's device time goes by the program's named
-scopes, and what the host was doing while the chip sat idle, by the
-program's own host spans.
+"""The one reduction of a profiler trace of the measured window to the
+numbers the per-layer readers read (``ctx["trace"]``).
 
-Reads the same ``.xplane.pb`` as :mod:`benchmarks.chip.trace`, on the
-same clock, for what that reduction does not split:
+On the device planes (absent on a CPU, where only the host keys below
+are given):
 
+* ``busy_s``, ``window_s``, ``idle_pct``: the union of the intervals in
+  which an XLA operation ran on a chip, inside the window, averaged over
+  the chips;
+* ``programs``: jitted program (XLA module) -> device seconds, summed
+  over the chips;
+* ``device_ops``: the ``TOP`` longest ops, by HLO instruction name;
 * ``scopes``: scope (``predict``, ``search``: the ``jax.named_scope``s of
   ``repro.index.index.lookup_impl``) -> device seconds, the union of the
   intervals of that scope's ops inside the window, so an op nested in a
@@ -15,26 +20,35 @@ same clock, for what that reduction does not split:
   route, rank rebasing);
 * ``unscoped_ops``: the ``TOP`` longest ops under no scope, custom calls
   named with their target (``%custom-call.16 X64SplitHigh``);
-* ``idle_by_span``: innermost host span -> idle seconds, over all idle
-  time, averaged over the chips (``"other"`` where the host was in no
-  span): it sums to the window's idle time;
-* ``idle_gaps``: the ``TOP`` longest idle gaps, each named for the host
-  spans it overlaps most, ``<outermost>/<innermost>``
-  (``entry_call/tier.telemetry.pull``), or ``"other"``.
+* ``idle_by_span``: innermost collected host span -> idle seconds, over
+  all idle time, averaged over the chips (``"other"`` where the host was
+  in no collected span): it sums to the window's idle time;
+* ``idle_gaps``: the ``TOP`` longest idle gaps, each named for the
+  innermost collected span it overlaps most, or ``"other"``.
+
+On the host plane, with or without a device plane:
+
+* ``window_s``: the length of the host's ``window`` span;
+* ``span_s``: collected span -> seconds the host spent under it inside
+  the window (the union of its occurrences).
+
+The collected spans are the harness's own (``trace.HOST_SPANS``) and
+those the cell's readers declare (``SPANS`` in ``layers/<metric>.py``,
+e.g. ``tier.telemetry.pull``, written by ``repro.obs.span``); spans on
+one thread nest by time.
 
 The device events carry no ``op_name`` for some ops (a ``%while`` has
 none), so an op's scope is read from the compiled HLO module the trace
 keeps for each program on its ``/host:metadata`` plane: once per
 instruction, not per event.  JAX's persistent compilation cache keys a
 program without that metadata, so a run whose executable was compiled
-from a source without the scopes (and cached) reads none.  Host spans
-are the benchmark's own (``trace.HOST_SPANS``) and the program's
-(``PROGRAM_SPANS``, written by ``repro.obs.span``); spans on one thread
-nest by time.
+from a source without the scopes (and cached) reads none: the traced
+run compiles without that cache (``run.py``).
 
     python3 benchmarks/chip/scopes.py <trace.xplane.pb>
 
-prints the reduction as one JSON line.
+prints the reduction as one JSON line, collecting the spans that any
+reader in ``layers/`` declares.
 """
 
 from __future__ import annotations
@@ -58,14 +72,13 @@ from benchmarks.chip.trace import (  # noqa: E402
     WINDOW_SPAN,
     busy_ns,
     clip,
+    collected_spans,
     idle_gaps,
     op_name,
     program_name,
 )
 
 SCOPES = ("predict", "search")
-#: the program's own host spans (``dist.sharded_index._record_tier_metrics``)
-PROGRAM_SPANS = ("tier.telemetry", "tier.telemetry.pull", "tier.telemetry.record")
 OTHER = "other"
 _METADATA_PLANE = "/host:metadata"
 _HLO_STAT = "Hlo Proto"
@@ -183,15 +196,17 @@ def program_scopes(xspace: bytes) -> dict:
     return out
 
 
-def load(path: str) -> tuple:
+def load(path: str, names: tuple = HOST_SPANS) -> tuple:
     """``(chips, spans, window, op_scopes)`` from an ``.xplane.pb`` file:
-    as :func:`benchmarks.chip.trace.load`, with the program's spans
-    among the host spans and each program's scoped instructions."""
+    the device planes' op and program events (``chips``: chip id ->
+    ``{"ops": [(name, s, e)], "modules": [(name, s, e)]}``), the host
+    spans named in ``names``, the interval of the host's ``window`` span
+    (all on the profiler's one clock), and each program's scoped
+    instructions (:func:`program_scopes`)."""
     from jax.profiler import ProfileData
 
     raw = Path(path).read_bytes()
     chips, spans, window = {}, [], None
-    names = (*HOST_SPANS, *PROGRAM_SPANS)
     for plane in ProfileData.from_serialized_xspace(raw).planes:
         m = _DEVICE_PLANE.match(plane.name)
         for line in plane.lines:
@@ -262,15 +277,15 @@ def idle_by_span(gaps, pieces) -> dict:
 
 
 def gap_name(gap, pieces, starts) -> str:
-    """The span path a gap overlaps most, ``<outermost>/<innermost>``, or
-    ``"other"``; ``starts`` are the pieces' starts (for bisection)."""
+    """The innermost span a gap overlaps most, or ``"other"``; ``starts``
+    are the pieces' starts (for bisection)."""
     by = defaultdict(float)
     k = max(bisect.bisect_right(starts, gap[0]) - 1, 0)
     while k < len(pieces) and pieces[k][0] < gap[1]:
         s, e, path = pieces[k]
         ov = min(e, gap[1]) - max(s, gap[0])
         if ov > 0:
-            by[path[0] if len(path) == 1 else f"{path[0]}/{path[-1]}"] += ov
+            by[path[-1]] += ov
         k += 1
     return max(by, key=by.get) if by else OTHER
 
@@ -278,21 +293,46 @@ def gap_name(gap, pieces, starts) -> str:
 # -- the reduction ----------------------------------------------------------------
 
 
-def reduce(chips: dict, spans: list, window: tuple, op_scopes: dict | None = None) -> dict:
-    """``chips``, ``spans`` and ``window`` as :func:`benchmarks.chip.trace.reduce`
-    takes them; ``op_scopes``: program event name -> ``{%instruction:
-    scope}`` (:func:`program_scopes`).  Returns the keys the module
-    docstring lists, in seconds."""
+def reduce(chips: dict, spans: list, window: tuple, op_scopes: dict | None = None,
+           names: tuple = HOST_SPANS) -> dict:
+    """``chips`` and ``op_scopes`` as :func:`load` gives them; ``spans``:
+    host ``(name, s, e)``, of which those named in ``names`` are
+    collected; ``window``: ``(start_ns, end_ns)``.  Returns the keys the
+    module docstring lists, in seconds; only the host keys where there is
+    no device plane, and nothing for an empty window.  Ops nest: a
+    ``%while`` op's time holds the ops of its body, so ``device_ops`` may
+    add up past ``busy_s``."""
     lo, hi = window
-    if not chips or hi <= lo:
+    if hi <= lo:
         return {}
+    collected = [sp for sp in spans if sp[0] in names]
+    pieces = []
+    for s, e, path in span_pieces(collected):
+        pieces += [(cs, ce, path) for cs, ce in clip([(s, e)], lo, hi)]
+    by_name = defaultdict(list)
+    for name, s, e in collected:
+        by_name[name].append((s, e))
+    window_s = (hi - lo) * 1e-9
+    host = {"window_s": window_s,
+            "span_s": {name: busy_ns(iv, lo, hi) * 1e-9 for name, iv in by_name.items()}}
+    if not chips:
+        return host
     op_scopes = op_scopes or {}
-    scopes, unscoped, unscoped_ops, gaps = defaultdict(float), defaultdict(float), defaultdict(float), []
+    busy, programs, ops, gaps = [], defaultdict(float), defaultdict(float), []
+    scopes, unscoped, unscoped_ops = defaultdict(float), defaultdict(float), defaultdict(float)
     for ev in chips.values():
+        intervals = [(s, e) for _, s, e in ev["ops"]]
+        busy.append(busy_ns(intervals, lo, hi))
+        for name, s, e in ev["modules"]:
+            if (t := seconds_inside(s, e, lo, hi)) > 0:
+                programs[program_name(name)] += t
         mods = sorted((s, e, name) for name, s, e in ev["modules"])
         starts = [m[0] for m in mods]
         by_scope, by_prog, scoped_by_prog = defaultdict(list), defaultdict(list), defaultdict(list)
         for name, s, e in ev["ops"]:
+            t = seconds_inside(s, e, lo, hi)
+            if t > 0:
+                ops[op_name(name)] += t
             k = bisect.bisect_right(starts, s) - 1
             prog = mods[k][2] if k >= 0 and s < mods[k][1] else None
             sc = op_scopes.get(prog, {}).get(op_name(name))
@@ -300,35 +340,51 @@ def reduce(chips: dict, spans: list, window: tuple, op_scopes: dict | None = Non
             if sc:
                 by_scope[sc].append((s, e))
                 scoped_by_prog[prog].append((s, e))
-            else:
-                for cs, ce in clip([(s, e)], lo, hi):
-                    unscoped_ops[op_label(name)] += (ce - cs) * 1e-9
+            elif t > 0:
+                unscoped_ops[op_label(name)] += t
         for sc, iv in by_scope.items():
             scopes[sc] += busy_ns(iv, lo, hi) * 1e-9
         for prog, iv in by_prog.items():
             ns = busy_ns(iv, lo, hi) - busy_ns(scoped_by_prog[prog], lo, hi)
             unscoped[OTHER if prog is None else program_name(prog)] += ns * 1e-9
-        gaps += idle_gaps([(s, e) for _, s, e in ev["ops"]], lo, hi)
-    gaps.sort()
-    names = (*HOST_SPANS, *PROGRAM_SPANS)
-    pieces = []
-    for s, e, path in span_pieces([sp for sp in spans if sp[0] in names]):
-        pieces += [(cs, ce, path) for cs, ce in clip([(s, e)], lo, hi)]
-    starts = [p[0] for p in pieces]
+        gaps += idle_gaps(intervals, lo, hi)
+    # only the longest gaps are named: a window holds as many gaps as ops
     longest = sorted(gaps, key=lambda g: g[0] - g[1])[:TOP]
+    gaps.sort()
+    starts = [p[0] for p in pieces]
+    busy_s = sum(busy) / len(busy) * 1e-9
     return {
+        **host,
+        "busy_s": busy_s,
+        "idle_pct": 100.0 * (1.0 - busy_s / window_s),
+        "programs": dict(programs),
+        "device_ops": top(ops),
         "scopes": dict(scopes),
         "unscoped": dict(unscoped),
-        "unscoped_ops": sorted(([k, v] for k, v in unscoped_ops.items()), key=lambda x: -x[1])[:TOP],
+        "unscoped_ops": top(unscoped_ops),
         "idle_by_span": {k: v * 1e-9 / len(chips) for k, v in idle_by_span(gaps, pieces).items()},
         "idle_gaps": [[gap_name(g, pieces, starts), (g[1] - g[0]) * 1e-9] for g in longest],
     }
 
 
+def seconds_inside(s: float, e: float, lo: float, hi: float) -> float:
+    """Seconds of ``[s, e]`` (ns) inside ``[lo, hi]``; 0 where none."""
+    return max(0.0, min(e, hi) - max(s, lo)) * 1e-9
+
+
+def top(seconds: dict) -> list:
+    """The ``TOP`` largest ``[name, seconds]`` pairs, largest first."""
+    return sorted(([k, v] for k, v in seconds.items()), key=lambda x: -x[1])[:TOP]
+
+
 def main(argv=None) -> int:
     (path,) = sys.argv[1:] if argv is None else argv
-    chips, spans, window, op_scopes = load(path)
-    print(json.dumps(reduce(chips, spans, window, op_scopes) if window else {}))
+    from benchmarks.chip import manifest
+
+    layers = Path(__file__).resolve().parent / "layers"
+    names = collected_spans(manifest.load_module(f) for f in sorted(layers.glob("*.py")))
+    chips, spans, window, op_scopes = load(path, names)
+    print(json.dumps(reduce(chips, spans, window, op_scopes, names) if window else {}))
     return 0
 
 

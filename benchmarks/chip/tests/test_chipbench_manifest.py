@@ -58,7 +58,8 @@ def test_a_new_config_and_mix_need_only_new_files(tmp_path):
     c = manifest.cell(root, "tiny-syrmi.tiny-uniform")
     assert c["config"]["keys"] == 40_000 and c["mix"]["batch"] == 512
     assert set(c["per_layer"]) == {"build_s", "device_idle_pct", "lookup_device_ms",
-                                   "lookup_roofline_pct"}
+                                   "lookup_roofline_pct", "lookup_predict_device_ms",
+                                   "lookup_search_device_ms", "lookup_unscoped_device_ms"}
     assert c["end_to_end"] == ["ops_per_s", "hbm_bytes_per_key", "model_space_pct", "setup_s"]
     assert "p99_ms" in manifest.cell(root, "tiny-pgmm-tier4.tiny-zipf")["end_to_end"]
 
@@ -102,15 +103,17 @@ def test_a_new_key_draw_needs_only_new_files(tmp_path):
     (lambda m: m["per_layer"][1].update(moves="no_such_metric"), "moves unknown"),
     (lambda m: m["end_to_end"][0].update(workloads=["tiny-syrmi.tiny-uniform"]), "does not report"),
     (lambda m: m["workloads"][0].update(traffic="tiny-four-callers"), "callers = 4 is not implemented"),
-    (lambda m: m["workloads"][0].update(traffic="tiny-no-loop"), "no loops/open.py"),
+    (lambda m: m["workloads"][0].update(traffic="tiny-no-loop"), "no loops/no-such-loop.py"),
     (lambda m: m["workloads"][0].update(config="tiny-rs"), "does not count kind 'RS'"),
+    (lambda m: m["per_layer"][0].update(name="tiny_bad_spans"), "SPANS must be a tuple"),
 ])
 def test_a_broken_manifest_is_refused(tmp_path, breakage, complaint):
     root = small_root(tmp_path)
     bench = root / "benchmarks" / "chip"
     uniform = json.loads((bench / "mixes" / "tiny-uniform.json").read_text())
     (bench / "mixes" / "tiny-four-callers.json").write_text(json.dumps({**uniform, "callers": 4}))
-    (bench / "mixes" / "tiny-no-loop.json").write_text(json.dumps({**uniform, "loop": "open"}))
+    (bench / "mixes" / "tiny-no-loop.json").write_text(json.dumps({**uniform, "loop": "no-such-loop"}))
+    (bench / "layers" / "tiny_bad_spans.py").write_text('SPANS = "tier.telemetry"\n')
     rs = json.loads((bench / "configs" / "tiny-syrmi.json").read_text())
     (bench / "configs" / "tiny-rs.json").write_text(json.dumps({**rs, "name": "tiny-rs", "kind": "RS"}))
     man = manifest.load(root)

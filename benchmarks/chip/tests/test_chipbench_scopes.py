@@ -4,16 +4,27 @@ lists with known answers, and on a small trace recorded on a TPU v5e."""
 from __future__ import annotations
 
 import gzip
+import json
+import os
+import subprocess
+import sys
 import time
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-from benchmarks.chip import scopes, trace
+from benchmarks.chip import manifest, scopes, trace
+from chipbench_fixtures import REPO
 
 MS = 1_000_000  # ns
 RECORDED = Path(__file__).parent / "data" / "tier4-8m-keys.xplane.pb.gz"
+READERS = {name: manifest.load_module(manifest.bench_file(REPO, "layers", name, ".py"))
+           for name in ("lookup_predict_device_ms", "lookup_search_device_ms",
+                        "lookup_unscoped_device_ms", "tier_sync_idle_pct", "tier_device_ms")}
+TIER_SPANS = READERS["tier_sync_idle_pct"].SPANS
+#: the host spans the tier cell's readers collect
+TIER_NAMES = trace.collected_spans(READERS.values())
 
 
 def test_scope_union_counts_a_while_and_its_body_once():
@@ -60,7 +71,7 @@ def test_idle_goes_to_the_innermost_span_and_sums_to_the_idle_time():
              1: {"ops": [("a", 0, 4 * MS)], "modules": []}}
     spans = [("entry_call", 1 * MS, 9 * MS), ("tier.telemetry", 3 * MS, 6 * MS),
              ("tier.telemetry.pull", 3 * MS, 5 * MS), ("unrelated", 0, 10 * MS)]
-    r = scopes.reduce(chips, spans, (0, 10 * MS))
+    r = scopes.reduce(chips, spans, (0, 10 * MS), names=TIER_NAMES)
     by = r["idle_by_span"]
     # chip 0 idle [2,6] [7,10], chip 1 idle [4,10], averaged over the two chips
     assert by["tier.telemetry.pull"] == pytest.approx((0.002 + 0.001) / 2)
@@ -70,7 +81,7 @@ def test_idle_goes_to_the_innermost_span_and_sums_to_the_idle_time():
     idle_s = trace.reduce(chips, spans, (0, 10 * MS))["idle_pct"] / 100 * 0.010
     assert sum(by.values()) == pytest.approx(idle_s)
     assert r["idle_gaps"][0] == ["entry_call", pytest.approx(0.006)]  # chip 1's [4,10]
-    assert ["entry_call/tier.telemetry.pull", pytest.approx(0.004)] in r["idle_gaps"]  # chip 0's [2,6]
+    assert ["tier.telemetry.pull", pytest.approx(0.004)] in r["idle_gaps"]  # chip 0's [2,6]
 
 
 def test_span_pieces_nest_by_time():
@@ -99,25 +110,56 @@ def test_the_sweep_names_a_million_gaps_in_seconds():
     assert took < 10.0, f"{took:.1f} s"
 
 
-def test_recorded_tier_trace(tmp_path):
-    """A 12-request window of the 4-shard PGM_M tier at 8M keys, traced on
-    a TPU v5e: the old reduction reads what it read before this module
-    existed, and the new one accounts for every busy and idle second."""
-    path = tmp_path / "tier.xplane.pb"
-    path.write_bytes(gzip.decompress(RECORDED.read_bytes()))
-    chips, spans, window = trace.load(str(path))
-    old = trace.reduce(chips, spans, window)
-    assert old["busy_s"] == pytest.approx(0.183856626, rel=1e-9)
-    assert old["idle_pct"] == pytest.approx(18.96095172918755, rel=1e-9)
-    assert old["programs"] == pytest.approx({"jit__lookup_vmapped": 0.180861757,
-                                             "jit__owner_histogram": 0.002965798,
-                                             "jit_equal": 3.375e-05}, rel=1e-9)
-    assert old["idle_gaps"][0] == ["entry_call", pytest.approx(0.002920032, rel=1e-9)]
+#: the reduction's keys on the recorded trace before it was one reduction
+#: (``trace.reduce`` of the parent commit, host spans ``trace.HOST_SPANS``)
+PARENT_KEYS = {
+    "busy_s": 0.18385662600000002, "window_s": 0.226874118, "idle_pct": 18.96095172918755,
+    "programs": {"jit__lookup_vmapped": 0.18086175699999998, "jit__owner_histogram": 0.002965798,
+                 "jit_equal": 3.375e-05},
+    "device_ops": [["%while.9", 0.06799691499999999], ["%while.8", 0.06799665099999999],
+                   ["%fusion.235", 0.033956914000000026], ["%fusion.236", 0.03395652999999997],
+                   ["%fusion.240", 0.03395651599999998], ["%fusion.239", 0.03395625399999998],
+                   ["%fusion", 0.0029351520000000008], ["%fusion.36", 0.002124654],
+                   ["%fusion.26", 0.002122943], ["%fusion.20", 0.0021223]],
+    "idle_gaps": [["entry_call", 0.0029200320000000004], ["entry_call", 0.0026634690000000003],
+                  ["entry_call", 0.002265081], ["entry_call", 0.002263579],
+                  ["entry_call", 0.002232249], ["entry_call", 0.002102671],
+                  ["entry_call", 0.002095819], ["entry_call", 0.0020804160000000003],
+                  ["entry_call", 0.0020360580000000003], ["entry_call", 0.002005189]],
+}
+RECORDED_REQUESTS = 12
 
-    chips, spans, window, op_scopes = scopes.load(str(path))
+
+def approx(value):
+    """``pytest.approx`` to 1e-9, into ``[name, seconds]`` lists."""
+    if isinstance(value, list):
+        return [[name, pytest.approx(v, rel=1e-9)] for name, v in value]
+    return pytest.approx(value, rel=1e-9)
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    path = tmp_path_factory.mktemp("trace") / "tier.xplane.pb"
+    path.write_bytes(gzip.decompress(RECORDED.read_bytes()))
+    return path
+
+
+def test_recorded_tier_trace(recorded):
+    """A 12-request window of the 4-shard PGM_M tier at 8M keys, traced on
+    a TPU v5e: the keys the reduction had before it took scopes and
+    program spans read as they did, and the scopes and spans account for
+    every busy and idle second."""
+    chips, spans, window, op_scopes = trace.load(str(recorded))
+    old = trace.reduce(chips, spans, window)
+    for key, want in PARENT_KEYS.items():
+        assert old[key] == approx(want), key
+
+    chips, spans, window, op_scopes = trace.load(str(recorded), TIER_NAMES)
     assert op_scopes["jit__lookup_vmapped(723856815974040453)"]["%while.8"] == "predict"
     assert op_scopes["jit__lookup_vmapped(723856815974040453)"]["%while.9"] == "search"
-    r = scopes.reduce(chips, spans, window, op_scopes)
+    r = trace.reduce(chips, spans, window, op_scopes, TIER_NAMES)
+    for key in ("busy_s", "window_s", "idle_pct", "programs", "device_ops"):
+        assert r[key] == approx(PARENT_KEYS[key]), key
     device_s = sum(r["scopes"].values()) + sum(r["unscoped"].values())
     assert device_s == pytest.approx(old["busy_s"], rel=1e-9)
     assert r["scopes"]["predict"] > 0 and r["scopes"]["search"] > 0
@@ -125,14 +167,55 @@ def test_recorded_tier_trace(tmp_path):
     assert "%custom-call.18 X64SplitHigh" in top and "%custom-call.19 X64SplitLow" in top
     idle_s = old["window_s"] - old["busy_s"]
     assert sum(r["idle_by_span"].values()) == pytest.approx(idle_s, rel=1e-9)
-    assert set(scopes.PROGRAM_SPANS) <= set(r["idle_by_span"])
-    assert any(name == "entry_call/tier.telemetry.pull" for name, _ in r["idle_gaps"])
+    assert set(TIER_SPANS) <= set(r["idle_by_span"]) <= {*TIER_NAMES, "other"}
+    assert any(name == "tier.telemetry.pull" for name, _ in r["idle_gaps"])
+    assert 0 < r["span_s"]["tier.telemetry.pull"] < r["span_s"]["tier.telemetry"] < r["span_s"]["entry_call"]
+
+
+def test_the_scope_readers_read_what_the_command_line_prints(recorded):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("PYTHONPATH", None)
+    p = subprocess.run([sys.executable, "benchmarks/chip/scopes.py", str(recorded)], cwd=REPO,
+                       env=env, capture_output=True, text=True, timeout=120, check=True)
+    cli = json.loads(p.stdout.splitlines()[-1])
+    chips, spans, window, op_scopes = trace.load(str(recorded), TIER_NAMES)
+    ctx = {"trace": trace.reduce(chips, spans, window, op_scopes, TIER_NAMES),
+           "requests": RECORDED_REQUESTS}
+    got = {name: mod.read(ctx) for name, mod in READERS.items()}
+    per_request = 1e3 / RECORDED_REQUESTS
+    unscoped = READERS["lookup_unscoped_device_ms"].PROGRAMS
+    assert got == {
+        "lookup_predict_device_ms": pytest.approx(cli["scopes"]["predict"] * per_request, rel=1e-12),
+        "lookup_search_device_ms": pytest.approx(cli["scopes"]["search"] * per_request, rel=1e-12),
+        "lookup_unscoped_device_ms": pytest.approx(
+            sum(v for k, v in cli["unscoped"].items() if any(n in k for n in unscoped)) * per_request,
+            rel=1e-12),
+        "tier_sync_idle_pct": pytest.approx(
+            100 * sum(cli["idle_by_span"][n] for n in TIER_SPANS) / cli["window_s"], rel=1e-12),
+        "tier_device_ms": pytest.approx(
+            (cli["programs"]["jit__lookup_vmapped"] + cli["programs"]["jit__owner_histogram"])
+            * per_request, rel=1e-12),
+    }
+    # the scopes and the unscoped rest make up the tier's programs
+    split = sum(got[n] for n in ("lookup_predict_device_ms", "lookup_search_device_ms",
+                                 "lookup_unscoped_device_ms"))
+    assert split == pytest.approx(got["tier_device_ms"], rel=1e-3)
+    assert got["tier_sync_idle_pct"] <= cli["idle_pct"]
+
+    # a program compiled without its scopes, and a cell that collects no tier span
+    bare = trace.reduce(chips, spans, window, {}, trace.HOST_SPANS)
+    assert bare["scopes"] == {}
+    assert {name: mod.read({"trace": bare, "requests": RECORDED_REQUESTS})
+            for name, mod in READERS.items() if name != "tier_device_ms"} == dict.fromkeys(
+        ("lookup_predict_device_ms", "lookup_search_device_ms", "lookup_unscoped_device_ms",
+         "tier_sync_idle_pct"))
 
 
 def test_a_trace_without_scopes_or_program_spans():
     chips = {0: {"ops": [("%fusion.1 = f32[4] fusion()", 0, 2 * MS)], "modules": [("jit_f(1)", 0, 2 * MS)]}}
-    r = scopes.reduce(chips, [("entry_call", 0, 4 * MS)], (0, 4 * MS), {})
+    r = scopes.reduce(chips, [("entry_call", 0, 4 * MS)], (0, 4 * MS), {}, TIER_NAMES)
     assert r["scopes"] == {}
     assert r["unscoped"] == {"jit_f": pytest.approx(0.002)}
-    assert not set(scopes.PROGRAM_SPANS) & set(r["idle_by_span"])
-    assert scopes.reduce({}, [], (0, 1)) == {}
+    assert not set(TIER_SPANS) & set(r["idle_by_span"])
+    assert scopes.reduce({}, [], (0, 1)) == {"window_s": pytest.approx(1e-9), "span_s": {}}
+    assert scopes.reduce({}, [], (1, 1)) == {}

@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+from types import SimpleNamespace
+
 import pytest
 
 from benchmarks.chip import trace
@@ -40,6 +42,27 @@ def test_reduce_gives_busy_idle_programs_and_named_gaps():
     assert r["idle_gaps"] == [["answer_to_host", pytest.approx(0.002)],
                               ["entry_call", pytest.approx(0.002)],
                               ["entry_call", pytest.approx(0.001)]]
+    # host seconds under each collected span; "unrelated" is not collected
+    assert r["span_s"] == {"entry_call": pytest.approx(0.00275), "answer_to_host": pytest.approx(0.002),
+                           "make_request": pytest.approx(0.00025)}
+
+
+def test_gaps_are_named_as_before_unless_a_reader_declares_the_program_span():
+    chips = {0: {"ops": [("a", 0, 2 * MS), ("b", 6 * MS, 10 * MS)], "modules": []}}
+    spans = [("entry_call", 1 * MS, 9 * MS), ("tier.telemetry.pull", 2 * MS + MS // 2, 5 * MS + MS // 2)]
+    assert trace.reduce(chips, spans, (0, 10 * MS))["idle_gaps"] == [
+        ["entry_call", pytest.approx(0.004)]]
+    names = trace.collected_spans([SimpleNamespace(SPANS=("tier.telemetry.pull",))])
+    r = trace.reduce(chips, spans, (0, 10 * MS), names=names)
+    assert r["idle_gaps"] == [["tier.telemetry.pull", pytest.approx(0.004)]]
+    assert r["idle_by_span"] == {"entry_call": pytest.approx(0.001),
+                                 "tier.telemetry.pull": pytest.approx(0.003)}
+
+
+def test_collected_spans_are_the_harness_s_then_the_readers_once_each():
+    readers = [SimpleNamespace(), SimpleNamespace(SPANS=("b.x", "entry_call", "b.x")),
+               SimpleNamespace(SPANS=("c",))]
+    assert trace.collected_spans(readers) == (*trace.HOST_SPANS, "b.x", "c")
 
 
 def test_two_chips_average_busy_and_clip_to_the_window():
@@ -50,7 +73,8 @@ def test_two_chips_average_busy_and_clip_to_the_window():
     r = trace.reduce(chips, [], (0, 4 * MS))
     assert r["busy_s"] == pytest.approx(0.003)  # (2 + 4) / 2 ms
     assert r["idle_gaps"] == [["other", pytest.approx(0.002)]]
-    assert trace.reduce({}, [], (0, 1)) == {}
+    # no device plane (a CPU): the host keys alone
+    assert trace.reduce({}, [], (0, 1)) == {"window_s": pytest.approx(1e-9), "span_s": {}}
 
 
 def test_names_drop_run_ids_and_hlo_text():
